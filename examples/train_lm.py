@@ -12,6 +12,7 @@ import tempfile
 
 from repro.checkpoint import Checkpointer
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import synthetic_data
 from repro.training import AdamWConfig, TrainConfig, train
 
@@ -22,6 +23,7 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # ~100M params: qwen1.5 family topology, 12 x 512 width
     cfg = dataclasses.replace(

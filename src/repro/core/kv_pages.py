@@ -310,8 +310,12 @@ class PagedKVCache:
             # no draft pages present the ordering is exactly the
             # pre-spec LRU, bit-identical with the mode off
             if self.prefetch_on:
-                pg = min(victims, key=lambda p: (not p.draft, p.hits,
-                                                 p.last_use, p.pid))
+                # pages staged for a pending dispatch go last: making room
+                # for one prefetch group must not evict the group before it
+                # (prefetch's headroom check counts only unstaged pages)
+                pg = min(victims, key=lambda p: (
+                    not p.draft, p.pid in self._prefetched, p.hits,
+                    p.last_use, p.pid))
             else:
                 pg = min(victims, key=lambda p: (not p.draft,
                                                  p.last_use, p.pid))

@@ -65,12 +65,12 @@ def int8_matmul(x, w, sx, sw, *, use_pallas: Optional[bool] = None,
 
 
 @functools.partial(jax.jit, static_argnames=("k", "use_pallas"))
-def topk_retrieval(queries, corpus, k: int, *,
+def topk_retrieval(queries, corpus, k: int, n_valid=None, *,
                    use_pallas: Optional[bool] = None):
     run, interp = _mode(use_pallas)
     if run:
-        return _topk(queries, corpus, k, interpret=interp)
-    return ref.topk_retrieval_ref(queries, corpus, k)
+        return _topk(queries, corpus, k, n_valid, interpret=interp)
+    return ref.topk_retrieval_ref(queries, corpus, k, n_valid)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))

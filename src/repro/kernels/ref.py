@@ -29,9 +29,11 @@ def int8_matmul_ref(x, w, sx, sw, out_dtype=jnp.bfloat16):
             * sw.astype(jnp.float32)).astype(out_dtype)
 
 
-def topk_retrieval_ref(queries, corpus, k):
+def topk_retrieval_ref(queries, corpus, k, n_valid=None):
     s = jnp.einsum("qd,nd->qn", queries.astype(jnp.float32),
                    corpus.astype(jnp.float32))
+    if n_valid is not None:
+        s = jnp.where(jnp.arange(s.shape[1]) < n_valid, s, -1e30)
     vals, idxs = jax.lax.top_k(s, k)
     return vals, idxs.astype(jnp.int32)
 

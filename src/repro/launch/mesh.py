@@ -11,31 +11,19 @@ from __future__ import annotations
 from typing import Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
-def compat_make_mesh(shape: Tuple[int, ...], axes: Sequence[str]):
-    """``jax.make_mesh`` with explicitly-Auto axis types where the
-    installed jax supports them.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg) only exist
-    from jax 0.5.x; older releases treat every axis as Auto implicitly, so
-    passing nothing is the same mesh.  Newer releases may flip the default
-    toward Explicit sharding — pinning Auto keeps HLO lowering identical
-    across versions (the hlo_cost walker depends on that)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:          # AxisType present but kwarg not accepted
-            pass
-    return jax.make_mesh(shape, axes)
+def auto_mesh(shape: Tuple[int, ...], axes: Sequence[str]):
+    """``jax.make_mesh`` with every axis Auto-sharded, so lowering does not
+    follow a changing default (the hlo_cost walker depends on it)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1, data: int = 1):
@@ -43,4 +31,4 @@ def make_host_mesh(model: int = 1, data: int = 1):
     n = len(jax.devices())
     model = min(model, n)
     data = max(min(data, n // model), 1)
-    return compat_make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))

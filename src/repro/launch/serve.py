@@ -1,77 +1,122 @@
 """Serving launcher: HeRo-orchestrated agentic RAG over real executors.
 
-    PYTHONPATH=src python -m repro.launch.serve --workflow 2 --queries 3
+    PYTHONPATH=src python -m repro.launch.serve --serve --queries 4
 
 Runs the full executable pipeline — chunker, embedder, vector DB, reranker,
-rewriter/planner agents, chat generation — with reduced-config stage models
-on heterogeneous PU-group executors under the HeRo scheduler.  On a pod
-this is the deployment entry point: each PUExecutor wraps one mesh slice;
-here each wraps a CPU worker (same control plane, the point of the dry-run
-separation).
+rewriter/planner agents, chat generation — on heterogeneous PU-group
+executors under the HeRo scheduler.  The stage models are the Qwen3 family
+(``configs/qwen3_family.py``) at their published widths in bf16, with
+seeded random weights; ``--reduced`` builds tiny float32 configs for CPU
+runs.  Every PU executor shares the default device.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
+from typing import Dict, Optional, Tuple
 
 import jax
 import numpy as np
 
 from repro.api import HeroSession, SessionOptions
-from repro.configs import get_family, reduced
+from repro.configs import ModelConfig, get_family, reduced
 from repro.core.spec_decode import DEFAULT_DRAFT_MODEL, is_draft_stage
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.rag import (HashTokenizer, VectorDB, chunk_documents,
                        default_means, sample_traces, shared_corpus_traces,
                        synth_documents, synth_query)
 from repro.rag.agents import LMAgent
-from repro.rag.embedder import Embedder, Reranker
+from repro.rag.embedder import CALL_WIDTH, Embedder, Reranker
 from repro.rag.stages import DRAFT_MODELS
 
+SEARCH_K = 4          # chunks each vector search retrieves
+PROMPT_TOKENS = 16    # prompt length of every agent call
+MAX_NEW_TOKENS = 8    # most tokens one agent call generates
+# a decode round never holds more streams than one agent call runs, so a
+# fused decode dispatch is one LMAgent call, not several in a row
+SERVE_OVERRIDES = {"decode_batch_cap": CALL_WIDTH}
 
-def build_pipeline(seed: int = 0):
-    fam = {k: reduced(v) for k, v in get_family("qwen3").items()}
+
+@dataclass
+class Pipeline:
+    """The built stage models, tokenizer and vector store."""
+
+    models: Dict[str, Tuple[ModelConfig, dict]]   # role -> (config, params)
+    tok: HashTokenizer
+    embedder: Embedder
+    reranker: Reranker
+    rewriter: LMAgent
+    chat: LMAgent
+    draft: Optional[LMAgent]
+    db: VectorDB
+
+
+def build_pipeline(seed: int = 0, *, reduced_widths: bool = False,
+                   spec_decode: bool = False) -> Pipeline:
+    """Build the Qwen3 stage family (plus the draft model when
+    ``spec_decode``) with weights drawn from ``seed``: role i of the family
+    is seeded with ``fold_in(seed, i)``."""
+    cfgs = dict(get_family("qwen3"))
+    if spec_decode:
+        cfgs["draft"] = DRAFT_MODELS[DEFAULT_DRAFT_MODEL]
+    if reduced_widths:
+        cfgs = {role: reduced(cfg) for role, cfg in cfgs.items()}
     key = jax.random.PRNGKey(seed)
-    models = {}
-    for role, cfg in fam.items():
-        params = build_model(cfg).init(jax.random.fold_in(key, hash(role) % 97))
-        models[role] = (cfg, params)
-    tok = HashTokenizer(fam["embed"].vocab_size)
-    embedder = Embedder(*models["embed"])
-    rerank = Reranker(*models["rerank"])
-    rewriter = LMAgent(*models["search"], max_len=256)
-    chat = LMAgent(*models["chat"], max_len=512)
-    dcfg = reduced(DRAFT_MODELS[DEFAULT_DRAFT_MODEL])
-    draft = LMAgent(dcfg, build_model(dcfg).init(
-        jax.random.fold_in(key, 991)), max_len=256)
-    return tok, embedder, rerank, rewriter, chat, draft
+    models = {role: (cfg, jax.jit(build_model(cfg).init)(
+                  jax.random.fold_in(key, i)))
+              for i, (role, cfg) in enumerate(cfgs.items())}
+    return Pipeline(
+        models=models,
+        tok=HashTokenizer(cfgs["embed"].vocab_size),
+        embedder=Embedder(*models["embed"]),
+        reranker=Reranker(*models["rerank"]),
+        rewriter=LMAgent(*models["search"], max_len=256),
+        chat=LMAgent(*models["chat"], max_len=512),
+        draft=(LMAgent(*models["draft"], max_len=256)
+               if spec_decode else None),
+        db=VectorDB(dim=cfgs["embed"].d_model))
 
 
-def build_stage_fns(seed: int = 0):
-    """Wire the executable pipeline into perf-stage callables — the
-    ``stage_fns`` a live-backend :class:`HeroSession` dispatches to."""
-    tok, embedder, reranker, rewriter, chat, draft = build_pipeline(seed)
+def stage_fns(pipe: Pipeline):
+    """Wire a built pipeline into perf-stage callables — the ``stage_fns``
+    a live-backend :class:`HeroSession` dispatches to.  Every model program
+    the fns run is compiled here, once, so no dispatch waits on a compile
+    (the straggler heartbeat would read one as a slow PU)."""
+    tok, embedder, reranker = pipe.tok, pipe.embedder, pipe.reranker
+    rewriter, chat, draft, db = pipe.rewriter, pipe.chat, pipe.draft, pipe.db
 
     docs = synth_documents(4, 400, seed=7)
     chunks = chunk_documents(docs, tok)
-    db = VectorDB(dim=embedder.cfg.d_model)
-    query = synth_query(seed=3)
-    q_ids = tok.encode(query)
+    q_ids = tok.encode(synth_query(seed=3))
+    prompt = q_ids[:PROMPT_TOKENS]
+    q_vec = embedder.embed([q_ids])
 
     def fn_embed(node, batch):
-        if node.stage == "embed" and "embed_chunks" in node.id:
+        ids = [m.id for m in node.payload.get("members", ())] or [node.id]
+        if any("embed_chunks" in i for i in ids):
             take = chunks[: max(batch, 1)]
-            db.add(np.asarray(embedder.embed([c.token_ids for c in take])))
+            db.add(embedder.embed([c.token_ids for c in take]))
             return len(take)
-        return np.asarray(embedder.embed([q_ids]))
+        return embedder.embed([q_ids])
 
     def fn_vsearch(node, batch):
-        return db.search(np.asarray(embedder.embed([q_ids])), k=4)
+        # the query vector is embed_query's output (a dependency of every
+        # vsearch node); this stage is the search itself
+        return db.search(q_vec, k=SEARCH_K)
 
     def fn_rerank(node, batch):
+        n = min(batch, CALL_WIDTH)
         scores = reranker.score(q_ids, [chunks[i % len(chunks)].token_ids
-                                        for i in range(min(batch, 8))])
+                                        for i in range(n)])
         return scores.tolist()
+
+    def need_draft():
+        if draft is None:
+            raise RuntimeError("speculative decoding needs "
+                               "build_pipeline(spec_decode=True)")
+        return draft
 
     def fn_llm(node, batch):
         if is_draft_stage(node.stage):
@@ -79,8 +124,8 @@ def build_stage_fns(seed: int = 0):
             # streams spec_width candidate tokens per verify pass
             # (workload = passes × width); candidates are greedy, so the
             # verify fn reproduces them for its acceptance comparison
-            return draft.generate(q_ids[:16],
-                                  max_new=min(node.workload, 16)).token_ids
+            return need_draft().generate(
+                prompt, max_new=min(node.workload, 16)).token_ids
         agent = rewriter if node.stage.startswith(("rewrite", "plan")) \
             else chat
         if node.kind == "stream_prefill":
@@ -90,8 +135,8 @@ def build_stage_fns(seed: int = 0):
             # resident continuous-batching decode round: ONE width-B JAX
             # call serves every member's token group; results slice back
             # per query (member id -> tokens)
-            group = max(1, min(batch, 8))
-            outs = agent.generate_batch([q_ids[:16]] * len(members),
+            group = max(1, min(batch, MAX_NEW_TOKENS))
+            outs = agent.generate_batch([prompt] * len(members),
                                         max_new=group)
             if node.payload.get("spec_width"):
                 # speculative verify pass: accept the drafted prefix that
@@ -99,23 +144,36 @@ def build_stage_fns(seed: int = 0):
                 # deterministic, so regenerating the draft's candidates
                 # here is exact) and stamp the per-member accept counts
                 # the round boundary folds into the accept-rate EWMA
-                douts = draft.generate_batch([q_ids[:16]] * len(members),
-                                             max_new=group)
+                douts = need_draft().generate_batch(
+                    [prompt] * len(members), max_new=group)
                 node.payload["spec_accepts"] = {
                     m.id: sum(1 for a, b in zip(g.token_ids, dg.token_ids)
                               if a == b)
                     for m, g, dg in zip(members, outs, douts)}
             return {m.id: g.token_ids for m, g in zip(members, outs)}
-        return agent.generate(q_ids[:16], max_new=min(batch, 8)).token_ids
+        return agent.generate(prompt,
+                              max_new=min(batch, MAX_NEW_TOKENS)).token_ids
 
-    stage_fns = {s: fn_llm for s in
-                 ("rewrite_prefill", "rewrite_decode", "plan_prefill",
-                  "plan_decode", "refine_prefill", "refine_decode",
-                  "chat_prefill", "chat_decode", "rewrite_draft",
-                  "plan_draft", "refine_draft", "chat_draft")}
-    stage_fns.update(embed=fn_embed, vsearch=fn_vsearch, rerank=fn_rerank,
-                     __io__=lambda n, b: time.sleep(0.05))
-    return stage_fns
+    reranker.score(q_ids, [chunks[0].token_ids])
+    for agent in (rewriter, chat, draft):
+        if agent is not None:
+            agent.generate(prompt, max_new=2, stop_at_eos=False)
+
+    fns = {s: fn_llm for s in
+           ("rewrite_prefill", "rewrite_decode", "plan_prefill",
+            "plan_decode", "refine_prefill", "refine_decode",
+            "chat_prefill", "chat_decode", "rewrite_draft",
+            "plan_draft", "refine_draft", "chat_draft")}
+    fns.update(embed=fn_embed, vsearch=fn_vsearch, rerank=fn_rerank,
+               __io__=lambda n, b: time.sleep(0.05))
+    return fns
+
+
+def build_stage_fns(seed: int = 0, *, reduced_widths: bool = False,
+                    spec_decode: bool = False):
+    """``stage_fns(build_pipeline(...))`` — published widths by default."""
+    return stage_fns(build_pipeline(seed, reduced_widths=reduced_widths,
+                                    spec_decode=spec_decode))
 
 
 def main():
@@ -140,7 +198,11 @@ def main():
                          "coupled (draft, verify) pairs — the real draft "
                          "model streams candidates the target verifies in "
                          "one sweep (implies --serve admission)")
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny float32 stage models (CPU runs) instead of "
+                         "the published widths")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.prefix_cache or args.spec_decode:
         args.serve = True
@@ -153,8 +215,11 @@ def main():
                        options=SessionOptions(
                            coalesce=bool(args.serve),
                            kv_pages=bool(args.prefix_cache),
-                           spec_decode=bool(args.spec_decode)),
-                       stage_fns=build_stage_fns())
+                           spec_decode=bool(args.spec_decode),
+                           cfg_overrides=SERVE_OVERRIDES),
+                       stage_fns=build_stage_fns(
+                           reduced_widths=args.reduced,
+                           spec_decode=args.spec_decode))
     for qi, tr in enumerate(traces):
         sess.submit(tr, wf=args.workflow,
                     arrival_time=qi * args.inter_arrival if args.serve

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint import Checkpointer
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.training import TrainConfig, train
 from repro.training.optimizer import AdamWConfig
 
@@ -57,6 +58,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -332,7 +332,7 @@ def apply(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
     new_cache: Optional[Params] = dict(cache) if cache is not None else None
 
     if fam == "dense":
-        x, (lc, _) = _run_dense_stack(
+        x, lc = _run_dense_stack(
             params["blocks"], cfg, x, positions,
             None if cache is None else cache["layers"], cache_idx, mode)
         if new_cache is not None:
@@ -371,23 +371,28 @@ def _logits(params: Params, x: jax.Array) -> jax.Array:
 
 
 def _run_dense_stack(stacked: Params, cfg: ModelConfig, x, positions,
-                     caches, cache_idx, mode, *, causal=True,
-                     cross_kv=None, cross_caches=None, window=0):
-    """lax.scan over a stacked homogeneous dense-block stack."""
+                     caches, cache_idx, mode, *, causal=True, window=0):
+    """lax.scan over a stacked homogeneous dense-block stack.
 
+    The stacked cache (None without one) rides the loop carry and each
+    layer writes its slice back, so XLA updates a donated cache in place
+    instead of stacking a second full copy as the scan's output."""
     def body(carry, xs):
-        h = carry
-        p, c, xc = xs
-        h, nc, nxc = dense_block(p, cfg, h, positions=positions,
-                                 causal=causal, cache=c, cache_idx=cache_idx,
-                                 window=window, cross_kv=cross_kv,
-                                 cross_cache=xc)
-        return h, (nc, nxc)
+        h, cs = carry
+        p, i = xs
+        h, nc, _ = dense_block(
+            p, cfg, h, positions=positions, causal=causal,
+            cache=jax.tree.map(lambda a: a[i], cs), cache_idx=cache_idx,
+            window=window)
+        cs = jax.tree.map(
+            lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
+            cs, nc)
+        return (h, cs), None
 
-    body = _remat(body, cfg, mode)
-    x, (new_caches, new_cross) = jax.lax.scan(
-        body, x, (stacked, caches, cross_caches))
-    return x, (new_caches, new_cross)
+    n_layers = jax.tree.leaves(stacked)[0].shape[0]
+    (x, caches), _ = jax.lax.scan(_remat(body, cfg, mode), (x, caches),
+                                  (stacked, jnp.arange(n_layers)))
+    return x, caches
 
 
 def _run_vlm(params, cfg, batch, x, positions, cache, cache_idx, mode,

@@ -1,9 +1,9 @@
 """Executable agents for the real (JAX) pipeline: query rewriter, search
 planner, context refiner, chat — thin generation loops over the model zoo.
 
-These run the tiny reduced configs in tests/examples (the full-size stage
-models are exercised through the dry-run); semantics match the simulator's
-workflow builders so the two paths stay in lockstep.
+The serving entry point runs them at published widths; tests and examples
+pass reduced configs.  Semantics match the simulator's workflow builders
+so the two paths stay in lockstep.
 """
 from __future__ import annotations
 
@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import Model, build_model
+from repro.rag.embedder import CALL_WIDTH
 from repro.rag.tokenizer import EOS
 
 
@@ -26,59 +27,74 @@ class GenResult:
 
 
 class LMAgent:
-    """Greedy decoding agent with prefill + stepwise decode (KV cache)."""
+    """Greedy decoding agent: jitted prefill, then jitted stepwise decode
+    through the KV cache.
+
+    Every call runs at one batch width (``CALL_WIDTH`` streams): fewer
+    streams are padded with copies of the first prompt, more are split
+    into groups that run one after another.  So each agent compiles
+    exactly one prefill and one decode program per prompt length, and a
+    warm agent compiles nothing.  The serving path caps decode rounds at
+    ``CALL_WIDTH`` members (``decode_batch_cap``), so one fused dispatch
+    is one call there."""
 
     def __init__(self, cfg: ModelConfig, params, max_len: int = 512):
         self.cfg = cfg
         self.params = params
         self.max_len = max_len
         self.model: Model = build_model(cfg)
-        self._decode = jax.jit(self.model.decode_step)
+        model = self.model
+
+        def prefill(params, tokens):
+            cache = model.init_cache(tokens.shape[0], max_len)
+            logits, cache = model.prefill(params, {"tokens": tokens}, cache)
+            return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), cache
+
+        def decode(params, tok, cache):
+            logits, cache = model.decode_step(params, tok[:, None], cache)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode, donate_argnums=2)
+
+    def _run(self, prompts: Sequence[Sequence[int]], max_new: int,
+             stop_at_eos: bool) -> List[List[int]]:
+        """One width-``CALL_WIDTH`` prefill + decode loop over at most
+        ``CALL_WIDTH`` prompts; stops early at EOS of the first stream only
+        when ``stop_at_eos``."""
+        n = len(prompts)
+        rows = list(prompts) + [prompts[0]] * (CALL_WIDTH - n)
+        tok, cache = self._prefill(self.params, jnp.asarray(rows, jnp.int32))
+        outs = [[int(t)] for t in np.asarray(tok)[:n]]
+        for _ in range(max_new - 1):
+            if stop_at_eos and outs[0][-1] == EOS:
+                break
+            tok, cache = self._decode(self.params, tok, cache)
+            for seq, t in zip(outs, np.asarray(tok)[:n]):
+                seq.append(int(t))
+        return outs
 
     def generate(self, prompt_ids: Sequence[int], max_new: int = 32,
                  stop_at_eos: bool = True) -> GenResult:
-        prompt = jnp.asarray([list(prompt_ids)], jnp.int32)
-        cache = self.model.init_cache(1, self.max_len)
-        logits, cache = self.model.prefill(self.params,
-                                           {"tokens": prompt}, cache)
-        tok = int(jnp.argmax(logits[0, -1]))
-        out = [tok]
-        for _ in range(max_new - 1):
-            if stop_at_eos and tok == EOS:
-                break
-            logits, cache = self._decode(
-                self.params, jnp.asarray([[tok]], jnp.int32), cache)
-            tok = int(jnp.argmax(logits[0]))
-            out.append(tok)
+        out = self._run([list(prompt_ids)], max_new, stop_at_eos)[0]
         return GenResult(out, len(out))
 
     def generate_batch(self, prompts: Sequence[Sequence[int]],
                        max_new: int = 32) -> List[GenResult]:
-        """One batched prefill + stepwise decode over ``len(prompts)``
+        """Batched prefill + stepwise decode over ``len(prompts)``
         concurrent streams — the continuous-batching serving path: a fused
         decode dispatch runs a single width-B JAX call per token step
         instead of B sequential single-stream loops.  The model applies no
         padding mask, so ragged prompts are LEFT-CROPPED to the shortest
         length (keeping each stream's most recent context) rather than
         padded — pad tokens would leak into attention at real positions."""
-        B = len(prompts)
-        if B == 1:
-            return [self.generate(prompts[0], max_new, stop_at_eos=False)]
-        assert all(len(p) > 0 for p in prompts), "empty prompt in batch"
-        width = min(len(p) for p in prompts)
-        cropped = [list(p)[-width:] for p in prompts]
-        tokens = jnp.asarray(cropped, jnp.int32)
-        cache = self.model.init_cache(B, self.max_len)
-        logits, cache = self.model.prefill(self.params,
-                                           {"tokens": tokens}, cache)
-        tok = jnp.argmax(logits[:, -1], axis=-1)
-        outs = [[int(t)] for t in np.asarray(tok)]
-        for _ in range(max_new - 1):
-            logits, cache = self._decode(
-                self.params, tok[:, None].astype(jnp.int32), cache)
-            tok = jnp.argmax(logits, axis=-1)
-            for seq, t in zip(outs, np.asarray(tok)):
-                seq.append(int(t))
+        assert prompts and all(len(p) > 0 for p in prompts), \
+            "empty prompt in batch"
+        length = min(len(p) for p in prompts)
+        cropped = [list(p)[-length:] for p in prompts]
+        outs: List[List[int]] = []
+        for i in range(0, len(cropped), CALL_WIDTH):
+            outs += self._run(cropped[i:i + CALL_WIDTH], max_new, False)
         return [GenResult(seq, len(seq)) for seq in outs]
 
 

@@ -4,7 +4,9 @@ Embedder: mean-pooled final hidden states, L2-normalized (bge / qwen3-
 embedding style).  Reranker: cross-encoder — scores [query SEP chunk]
 pairs via a scalar head on the first position's hidden state.
 Both batch over items, which is exactly the batchable workload HeRo's
-partitioner (Eq. 3) optimizes.
+partitioner (Eq. 3) optimizes.  Items run in micro-batches of
+``CALL_WIDTH`` (the last one zero-padded), so a dispatch of any size
+reuses one compiled program.
 """
 from __future__ import annotations
 
@@ -19,14 +21,22 @@ from repro.models import layers as L
 from repro.models import lm
 from repro.rag.tokenizer import SEP
 
+# batch width of every model call in the live pipeline: embed and rerank
+# micro-batches, LMAgent prefill/decode (agents.py) and the scheduler's
+# decode_batch_cap on the serving path (launch/serve.py)
+CALL_WIDTH = 8
 
-def _pad_batch(token_lists: Sequence[Sequence[int]], pad_to: int,
-               vocab: int) -> jnp.ndarray:
-    out = np.zeros((len(token_lists), pad_to), np.int32)
-    for i, ids in enumerate(token_lists):
-        ids = list(ids)[:pad_to]
-        out[i, : len(ids)] = np.clip(ids, 0, vocab - 1)
-    return jnp.asarray(out)
+
+def _micro_batches(token_lists: Sequence[Sequence[int]], pad_to: int,
+                   vocab: int):
+    """Yield (n_items, tokens (CALL_WIDTH, pad_to) int32) host arrays."""
+    for s in range(0, len(token_lists), CALL_WIDTH):
+        part = token_lists[s:s + CALL_WIDTH]
+        out = np.zeros((CALL_WIDTH, pad_to), np.int32)
+        for i, ids in enumerate(part):
+            ids = list(ids)[:pad_to]
+            out[i, : len(ids)] = np.clip(ids, 0, vocab - 1)
+        yield len(part), out
 
 
 def hidden_states(params, cfg: ModelConfig, tokens) -> jax.Array:
@@ -49,7 +59,7 @@ class Embedder:
 
         @jax.jit
         def _embed(params, tokens, mask):
-            h = hidden_states(params, cfg, tokens)
+            h = hidden_states(params, cfg, tokens).astype(jnp.float32)
             s = jnp.sum(h * mask[..., None], axis=1)
             emb = s / jnp.maximum(mask.sum(-1, keepdims=True), 1.0)
             return emb / jnp.maximum(
@@ -57,10 +67,13 @@ class Embedder:
 
         self._fn = _embed
 
-    def embed(self, token_lists: Sequence[Sequence[int]]) -> jax.Array:
-        tokens = _pad_batch(token_lists, self.max_tokens, self.cfg.vocab_size)
-        mask = (tokens != 0).astype(jnp.float32)
-        return self._fn(self.params, tokens, mask)
+    def embed(self, token_lists: Sequence[Sequence[int]]) -> np.ndarray:
+        """-> (len(token_lists), d_model) L2-normalized embeddings."""
+        outs = [np.asarray(self._fn(self.params, tokens,
+                                    (tokens != 0).astype(np.float32)))[:n]
+                for n, tokens in _micro_batches(token_lists, self.max_tokens,
+                                                self.cfg.vocab_size)]
+        return np.concatenate(outs)
 
 
 class Reranker:
@@ -73,12 +86,15 @@ class Reranker:
         def _score(params, tokens):
             h = hidden_states(params, cfg, tokens)
             w = params["embed"][SEP]          # reuse a row as the head
-            return jnp.einsum("bd,d->b", h[:, 0], w)
+            return jnp.einsum("bd,d->b", h[:, 0], w,
+                              preferred_element_type=jnp.float32)
 
         self._fn = _score
 
     def score(self, query_ids: Sequence[int],
               chunk_ids_list: Sequence[Sequence[int]]) -> np.ndarray:
         pairs = [list(query_ids) + [SEP] + list(c) for c in chunk_ids_list]
-        tokens = _pad_batch(pairs, self.max_tokens, self.cfg.vocab_size)
-        return np.asarray(self._fn(self.params, tokens))
+        return np.concatenate([
+            np.asarray(self._fn(self.params, tokens))[:n]
+            for n, tokens in _micro_batches(pairs, self.max_tokens,
+                                            self.cfg.vocab_size)])

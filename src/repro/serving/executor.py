@@ -211,9 +211,13 @@ class HeroRuntime:
             if now() > timeout:
                 raise TimeoutError("HeroRuntime timed out")
             if not inflight:
+                # sampled BEFORE the pass: a straggler that drains between
+                # the pass (which saw its PU busy) and a later check would
+                # otherwise read as a deadlock
+                draining = any(x.busy() for x in self.executors.values())
                 dispatch()
                 if not inflight and dag.unfinished():
-                    if any(x.busy() for x in self.executors.values()):
+                    if draining:
                         # cancelled stragglers are non-preemptible: the PU
                         # drains them off-book (not in inflight) and only
                         # then frees up — waiting is progress, not deadlock

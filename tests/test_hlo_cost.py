@@ -5,16 +5,13 @@ import jax.numpy as jnp
 import pytest
 
 from repro.launch.hlo_cost import analyze
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import auto_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    # compat_make_mesh pins Auto axis types where the installed jax has
-    # jax.sharding.AxisType, and degrades to a plain mesh on versions
-    # (like 0.4.x) that predate it
     n = len(jax.devices())
-    return compat_make_mesh((1, n), ("data", "model"))
+    return auto_mesh((1, n), ("data", "model"))
 
 
 def test_matmul_flops_exact(mesh):
@@ -42,10 +39,7 @@ def test_scan_trip_count_multiplies(mesh):
     want = 2 * D * D * D * L
     assert want <= r["flops"] <= want * 1.1
     # XLA's own analysis undercounts by ~L (the documented failure mode)
-    ca = comp.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # jax <= 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
-    xla = float(ca.get("flops", 0.0))
+    xla = float(comp.cost_analysis().get("flops", 0.0))
     assert xla < r["flops"] / 2
 
 

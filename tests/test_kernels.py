@@ -117,3 +117,23 @@ def test_ssd_chunk_sweep(b, nc, Q, H, P, N):
                                rtol=2e-4)
     np.testing.assert_allclose(np.asarray(S), np.asarray(wS), atol=2e-4,
                                rtol=2e-4)
+
+
+@pytest.mark.parametrize("N,n_valid,bn,dups", [
+    (1024, 300, 256, False),   # valid prefix ends inside a block
+    (1024, 3, 256, False),     # fewer valid rows than one block's worth
+    (512, 512, 128, False),    # whole corpus valid
+    (512, 200, 128, True),     # repeated rows: ties go to the lowest index
+])
+def test_topk_valid_prefix(N, n_valid, bn, dups):
+    key = jax.random.PRNGKey(5)
+    q = jax.random.normal(key, (8, 64))
+    c = jax.random.normal(jax.random.fold_in(key, 1), (N, 64))
+    if dups:
+        c = jnp.tile(c[:16], (N // 16, 1))
+    k = min(4, n_valid)
+    vals, idxs = topk_retrieval(q, c, k, jnp.int32(n_valid), block_q=8,
+                                block_n=bn, interpret=True)
+    wv, wi = ref.topk_retrieval_ref(q, c[:n_valid], k)
+    np.testing.assert_allclose(np.asarray(vals), np.asarray(wv), atol=1e-4)
+    assert (np.asarray(idxs) == np.asarray(wi)).all()

@@ -409,6 +409,10 @@ def test_prefetch_bytes_conservation_single_pu():
     hyp = pytest.importorskip("hypothesis")
     st_ = pytest.importorskip("hypothesis.strategies")
 
+    # staging a prefetch group used to evict the pages staged just before
+    # it, so the staged bytes outran the bytes that reached the PU
+    @hyp.example([(0, 0, 0.0), (0, 0, 0.0), (0, 3, 0.0), (2, 0, 0.0),
+                  (3, 1, 0.0), (1, 0, 1.0)])
     @hyp.given(st_.lists(st_.tuples(st_.integers(0, 3),     # op selector
                                     st_.integers(0, 7),     # page pick
                                     st_.floats(0.0, 1.0)),  # budget

@@ -53,6 +53,51 @@ def test_vectordb_incremental_add_consistency():
     assert ids[0, 0] == 42
 
 
+def test_vectordb_growth_reuses_search_program():
+    """Adds of any size and a growing store keep one write and one search
+    program: the row count is traced, not baked into the shapes."""
+    from repro.kernels import ops
+    db = VectorDB(dim=8, capacity=256)
+    rng = np.random.default_rng(2)
+    vecs = rng.normal(size=(120, 8)).astype(np.float32)
+    db.add(vecs[:5])
+    db.search(jax.numpy.asarray(vecs[:1]), k=3)
+    before = ops.topk_retrieval._cache_size()
+    for lo, hi in ((5, 6), (6, 40), (40, 120)):
+        db.add(vecs[lo:hi])
+        vals, ids = db.search(jax.numpy.asarray(vecs[[lo]]), k=3)
+        assert ids[0, 0] == lo
+    assert ops.topk_retrieval._cache_size() == before
+
+
+def test_vectordb_concurrent_adds_lose_nothing():
+    """Embed dispatches on several PUs add to one store at once: every row
+    lands exactly once and stays findable."""
+    import sys
+    import threading
+    db = VectorDB(dim=8, capacity=512)
+    rng = np.random.default_rng(3)
+    vecs = rng.normal(size=(16 * 12, 8)).astype(np.float32)
+    vecs /= np.linalg.norm(vecs, axis=1, keepdims=True)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(
+            target=db.add, args=(vecs[i * 12:(i + 1) * 12],),
+            kwargs={"ids": list(range(i * 12, (i + 1) * 12))})
+            for i in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert len(db) == len(vecs)
+    _, ids = db.search(jax.numpy.asarray(vecs), k=1)
+    assert (ids[:, 0] == np.arange(len(vecs))).all()
+
+
 def test_embedder_reranker_pipeline(rng):
     fam = {k: reduced(v) for k, v in get_family("qwen3").items()}
     e_cfg = fam["embed"]

@@ -96,6 +96,31 @@ def test_runtime_retry_on_exception(runtime_world):
     assert any(e[1] == "retry" for e in rt.events)
 
 
+class _DrainingExecutor(PUExecutor):
+    """Reports busy for its first ``busy_calls`` queries: a cancelled
+    straggler that finishes draining right after a dispatch pass saw it."""
+
+    def __init__(self, name, busy_calls):
+        super().__init__(name)
+        self.busy_calls = busy_calls
+
+    def busy(self):
+        if self.busy_calls > 0:
+            self.busy_calls -= 1
+            return True
+        return super().busy()
+
+
+def test_runtime_straggler_drain_is_not_a_deadlock(runtime_world):
+    soc, perf = runtime_world
+    dag = DynamicDAG()
+    dag.add(Node("n1", "a", "batchable", 4))
+    sched = HeroScheduler(perf, ["cpu"], soc.dram_bw, SchedulerConfig())
+    rt = HeroRuntime(sched, {"cpu": _DrainingExecutor("cpu", busy_calls=2)},
+                     {"a": lambda n, b: n.id})
+    assert rt.run(dag, timeout=30) == {"n1": "n1"}
+
+
 def test_runtime_elastic_membership(runtime_world):
     soc, perf = runtime_world
     sched = HeroScheduler(perf, ["cpu"], soc.dram_bw, SchedulerConfig())

@@ -115,7 +115,7 @@ def test_executable_pipeline_end_to_end():
     import sys
     import repro.launch.serve as serve_mod
     argv = sys.argv
-    sys.argv = ["serve", "--workflow", "2", "--queries", "1"]
+    sys.argv = ["serve", "--workflow", "2", "--queries", "1", "--reduced"]
     try:
         serve_mod.main()
     finally:
