@@ -1,5 +1,5 @@
 """Benchmark driver — one section per paper table/figure.
-Prints ``name,us_per_call,derived``-style CSV blocks per section.
+Prints one CSV block per section.
 
     python benchmarks/run.py --list          # enumerate sections
     python benchmarks/run.py --only Serving  # run matching sections only
@@ -37,8 +37,8 @@ def main() -> None:
     import argparse
 
     from benchmarks import (fig2_affinity, fig3_contention, fig5_qwen3,
-                            fig6_bge, grid_search, kernels_bench,
-                            multiquery, roofline, table3_ablation)
+                            fig6_bge, grid_search, multiquery,
+                            table3_ablation)
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="reduced sweeps for the big sections")
@@ -71,8 +71,6 @@ def main() -> None:
          multiquery.serving_metrics, {"regimes": ("specdec",)}),
         ("Serving-ablation_adaptive_vs_fixed_caps (CI gate)",
          multiquery.serving_ablation, {}),
-        ("Kernel_microbench", kernels_bench.run, {}),
-        ("Roofline_from_dryrun", roofline.run, {}),
     ]
     if args.list:
         for name, _, _ in sections:

@@ -11,9 +11,13 @@ Rules (each encodes an invariant a past PR re-derived by hand):
   literals in ``_note``/``_emit`` calls, ``_events.append`` tuples, or
   comparisons are rejected.  A typo'd emit fails *silently* today —
   the event lands on the timeline and every counter filter misses it.
+  The same holds for span and counter names: in the modules that record
+  spans (``SPAN_MODULES``), ``spans.span``/``spans.record`` must be
+  named by an ``SP_*`` constant and ``spans.count`` by a ``CT_*`` one,
+  never by a string literal.
 - **EVT002** — a compared string within edit distance 1 of a registered
-  event name is flagged as a probable typo even where raw strings are
-  otherwise allowed.
+  event, span or counter name is flagged as a probable typo even where
+  raw strings are otherwise allowed.
 - **CFG001** — every boolean ``SchedulerConfig`` knob defaults off
   unless declared in ``scheduler.BASELINE_ON_KNOBS``: a gate that
   defaults on silently changes the goldens' baseline physics.
@@ -49,7 +53,7 @@ from dataclasses import dataclass
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
-from repro.core.events import ALL_EVENTS
+from repro.core.events import ALL_COUNTERS, ALL_EVENTS, ALL_SPANS
 
 
 @dataclass(frozen=True)
@@ -70,6 +74,15 @@ EVENT_MODULES = frozenset({
     "serving/executor.py", "api/backends.py", "api/results.py",
     "api/session.py",
 })
+
+# modules that record spans and counters (repro.serving.spans, imported
+# as ``spans``): a raw name in a recorder call is banned here
+SPAN_MODULES = frozenset({
+    "serving/executor.py", "api/session.py", "rag/agents.py",
+    "rag/vectordb.py", "rag/embedder.py",
+})
+RECORDER_CALLS = frozenset({"span", "record", "count"})
+REGISTERED_NAMES = ALL_EVENTS | ALL_SPANS | ALL_COUNTERS
 
 # core/ modules allowed to use wall clock / stdlib random (none today;
 # the sanctioned rng is seeded np.random.default_rng, allowed anywhere)
@@ -107,10 +120,11 @@ def _lev_le1(a: str, b: str) -> bool:
 
 
 def _near_event(s: str) -> Optional[str]:
-    """The registered event ``s`` is probably a typo of, or None."""
-    if s in ALL_EVENTS or not (3 <= len(s) <= 20):
+    """The registered event, span or counter name ``s`` is probably a
+    typo of, or None."""
+    if s in REGISTERED_NAMES or not (3 <= len(s) <= 24):
         return None
-    for ev in sorted(ALL_EVENTS):
+    for ev in sorted(REGISTERED_NAMES):
         if _lev_le1(s, ev):
             return ev
     return None
@@ -129,13 +143,46 @@ def _str_operands(node: ast.expr) -> List[Tuple[int, str]]:
     return out
 
 
+def _recorder_name_violation(n: ast.Call, path: str
+                             ) -> Optional[Violation]:
+    """A ``spans.span/record/count`` call named by a string literal, or by
+    a constant of the wrong kind (spans take ``SP_*``, counts ``CT_*``)."""
+    f = n.func
+    if not (isinstance(f, ast.Attribute) and f.attr in RECORDER_CALLS
+            and isinstance(f.value, ast.Name) and f.value.id == "spans"
+            and n.args):
+        return None
+    name, want = n.args[0], "CT_" if f.attr == "count" else "SP_"
+    if isinstance(name, ast.Constant) and isinstance(name.value, str):
+        near = _near_event(name.value)
+        hint = (f" (a typo of {near!r}?)" if near is not None
+                else "" if name.value in REGISTERED_NAMES
+                else " (not a registered name)")
+        return Violation(path, name.lineno, "EVT001",
+                         f"raw name {name.value!r} in spans.{f.attr}()"
+                         f"{hint} — use the {want}* constant from "
+                         "repro.core.events")
+    if isinstance(name, ast.Name) and not name.id.startswith(want):
+        return Violation(path, name.lineno, "EVT001",
+                         f"spans.{f.attr}() named by {name.id} — it takes "
+                         f"a {want}* constant from repro.core.events")
+    return None
+
+
 def check_event_literals(tree: ast.AST, key: str,
                          path: str) -> List[Violation]:
-    if key not in EVENT_MODULES:
+    events, recorder = key in EVENT_MODULES, key in SPAN_MODULES
+    if not (events or recorder):
         return []
     out: List[Violation] = []
     for n in ast.walk(tree):
-        if isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute):
+        bad = (_recorder_name_violation(n, path)
+               if recorder and isinstance(n, ast.Call) else None)
+        if bad is not None:
+            out.append(bad)
+        elif not events:
+            continue
+        elif isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute):
             # self._note(timeline, t, event, node) / self._emit(t, ev, n)
             if n.func.attr in ("_note", "_emit") and len(n.args) >= 2:
                 ev_arg = n.args[-2]
@@ -163,11 +210,11 @@ def check_event_literals(tree: ast.AST, key: str,
         elif isinstance(n, ast.Compare):
             for op in [n.left] + list(n.comparators):
                 for line, s in _str_operands(op):
-                    if s in ALL_EVENTS:
+                    if s in REGISTERED_NAMES:
                         out.append(Violation(
                             path, line, "EVT001",
                             f"comparison against raw event string {s!r} "
-                            "— use the EV_* constant from "
+                            "— use the EV_*/SP_*/CT_* constant from "
                             "repro.core.events"))
                     else:
                         near = _near_event(s)
@@ -175,7 +222,7 @@ def check_event_literals(tree: ast.AST, key: str,
                             out.append(Violation(
                                 path, line, "EVT002",
                                 f"string {s!r} looks like a typo of "
-                                f"event {near!r} — typo'd event names "
+                                f"{near!r} — typo'd names "
                                 "silently drop counters"))
     return out
 

@@ -54,11 +54,12 @@ from repro.api.options import SLO_CLASSES, SessionOptions
 from repro.api.results import ADMIT_STAGE, QueryResult, collect_results
 from repro.api.spec import WorkflowSpec, builtin_spec
 from repro.core.dag import DynamicDAG, Node
-from repro.core.events import EV_DONE, EV_TOKENS
+from repro.core.events import EV_DONE, EV_TOKENS, SP_SESSION_BUILD
 from repro.core.perf_model import (GroundTruthPerf, LinearPerfModel, SoCSpec,
                                    snapdragon_8gen3, snapdragon_8gen4)
 from repro.core.scheduler import (HeroScheduler, SchedulerConfig,
                                   strategy_config)
+from repro.serving import spans
 
 SOCS = {"sd8gen3": snapdragon_8gen3, "sd8gen4": snapdragon_8gen4}
 STRATEGIES = ("llamacpp_gpu", "powerserve_npu", "ayo_like", "hero")
@@ -257,6 +258,22 @@ class HeroSession:
 
     def _run_shared(self, timeout: float) -> List[QueryResult]:
         handles = self._handles
+        with spans.span(SP_SESSION_BUILD, [f"q{h.qid}" for h in handles],
+                        mode="shared"):
+            dag, sched = self._build_shared(handles)
+        try:
+            run = self.backend.execute(dag, sched,
+                                       observer=self._observer(handles),
+                                       timeout=timeout)
+        finally:
+            for h in handles:
+                h._dag = None
+        self.last_run = run
+        return collect_results(dag, handles, run, self.backend.name)
+
+    def _build_shared(self, handles: List[QueryHandle]):
+        """-> (one DAG of every query, behind its admission gate; the
+        scheduler)."""
         specs, seen = [], set()
         for h in handles:
             if h.spec.name not in seen:
@@ -288,15 +305,7 @@ class HeroSession:
         # including ones expanders create mid-run
         sched.slo_classes = {(h.prefix[:-1] if h.prefix else ""): h.slo
                              for h in handles}
-        try:
-            run = self.backend.execute(dag, sched,
-                                       observer=self._observer(handles),
-                                       timeout=timeout)
-        finally:
-            for h in handles:
-                h._dag = None
-        self.last_run = run
-        return collect_results(dag, handles, run, self.backend.name)
+        return dag, sched
 
     def _run_isolated(self, timeout: float) -> List[QueryResult]:
         out: List[QueryResult] = []
@@ -304,14 +313,16 @@ class HeroSession:
             h.prefix = ""
             h.arrival_time = 0.0   # no gate in isolated mode: each query
             # runs from t=0 on its own DAG, so results must not offset by it
-            cfg = self._scheduler_cfg([h.spec])
-            fine = (self.fine_grained if self.fine_grained is not None
-                    else cfg.enable_partition)
-            dag = h.spec.build_dag(h.trace, fine_grained=fine,
-                                   validate=self.options.validate_spec)
-            h._dag = dag
-            sched = self._scheduler(cfg, [h.spec])
-            sched.slo_classes = {"": h.slo}
+            with spans.span(SP_SESSION_BUILD, [f"q{h.qid}"],
+                            mode="isolated"):
+                cfg = self._scheduler_cfg([h.spec])
+                fine = (self.fine_grained if self.fine_grained is not None
+                        else cfg.enable_partition)
+                dag = h.spec.build_dag(h.trace, fine_grained=fine,
+                                       validate=self.options.validate_spec)
+                h._dag = dag
+                sched = self._scheduler(cfg, [h.spec])
+                sched.slo_classes = {"": h.slo}
             try:
                 run = self.backend.execute(dag, sched,
                                            observer=self._observer([h]),

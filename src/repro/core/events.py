@@ -19,6 +19,10 @@ in :data:`ALL_EVENTS`.
 The constant *values* are the historical strings, so recorded
 timelines, goldens, and bench baselines are bit-identical across the
 migration.
+
+The live runtime's span recorder (``repro.serving.spans``) names its
+spans with the ``SP_*`` constants and its counters with the ``CT_*``
+constants below, under the same lint discipline.
 """
 from __future__ import annotations
 
@@ -59,3 +63,48 @@ REDISPATCH_EVENTS = (EV_REDISPATCH, EV_STRAGGLER, EV_RETRY)
 # spill tiers of the paged KV store ("dram"/"disk", vs. PU-name tiers);
 # a gather sourced from one of these is a fetch, not a migration
 SPILL_TIERS = ("dram", "disk")
+
+# -- spans of the live runtime (repro.serving.spans) --------------------------
+SP_SESSION_BUILD = "session.build"        # DAG assembly + scheduler build
+SP_RUNTIME_RUN = "runtime.run"            # t0 = the run's timeline epoch
+SP_RUNTIME_DISPATCH_PASS = "runtime.dispatch_pass"  # reap + schedule + launch
+SP_RUNTIME_HARVEST = "runtime.harvest"    # one finished task handled
+SP_RUNTIME_POLL = "runtime.poll"          # the loop's sleep
+SP_RUNTIME_READY_WAIT = "runtime.ready_wait"  # first seen ready -> launch
+SP_EXECUTOR_QUEUE = "executor.queue"      # submit -> worker start
+SP_EXECUTOR_RUN = "executor.run"          # a stage fn on its PU's worker
+SP_LM_CALL = "lm.call"                    # one width-CALL_WIDTH agent call
+SP_LM_PREFILL = "lm.prefill"
+SP_LM_STEP = "lm.step"                    # one decode step
+SP_LM_FETCH = "lm.fetch"                  # device -> host of the tokens
+SP_VDB_SEARCH = "vdb.search"
+SP_VDB_KERNEL = "vdb.kernel"              # the top-k program's enqueue
+SP_VDB_FETCH = "vdb.fetch"                # device -> host of the results
+SP_VDB_IDS = "vdb.ids"                    # row -> id mapping on the host
+SP_VDB_ADD = "vdb.add"
+SP_EMBED_CALL = "embed.call"
+SP_RERANK_CALL = "rerank.call"
+
+ALL_SPANS = frozenset({
+    SP_SESSION_BUILD, SP_RUNTIME_RUN, SP_RUNTIME_DISPATCH_PASS,
+    SP_RUNTIME_HARVEST, SP_RUNTIME_POLL, SP_RUNTIME_READY_WAIT,
+    SP_EXECUTOR_QUEUE, SP_EXECUTOR_RUN, SP_LM_CALL, SP_LM_PREFILL,
+    SP_LM_STEP, SP_LM_FETCH, SP_VDB_SEARCH, SP_VDB_KERNEL, SP_VDB_FETCH,
+    SP_VDB_IDS, SP_VDB_ADD, SP_EMBED_CALL, SP_RERANK_CALL,
+})
+
+# -- counters of the live runtime ---------------------------------------------
+CT_RUNTIME_PASSES = "runtime.passes"
+CT_EXECUTOR_CANCELLED_RUNS = "executor.cancelled_runs"  # ran to the end
+#                                                         after a cancel
+CT_LM_STEPS = "lm.steps"
+CT_LM_REAL_TOKENS = "lm.real_tokens"      # tokens returned to real rows
+CT_LM_PAD_ROWS = "lm.pad_rows"            # padding rows per call
+CT_LM_KV_BYTES_RESERVED = "lm.kv_bytes_reserved"
+CT_LM_KV_BYTES_USED = "lm.kv_bytes_used"
+
+ALL_COUNTERS = frozenset({
+    CT_RUNTIME_PASSES, CT_EXECUTOR_CANCELLED_RUNS, CT_LM_STEPS,
+    CT_LM_REAL_TOKENS, CT_LM_PAD_ROWS, CT_LM_KV_BYTES_RESERVED,
+    CT_LM_KV_BYTES_USED,
+})

@@ -198,6 +198,9 @@ class HeroScheduler:
         self.template = template
         self._fifo_seq: Dict[str, int] = {}
         self._seq = 0
+        # the ``now`` of the pass that saw each node ready, until the node
+        # is launched (take_ready_time): the live runtime's ready wait
+        self._ready_at: Dict[str, float] = {}
         if self.cfg.migrate_pricing not in ("modeled", "constant"):
             raise KeyError(f"migrate_pricing {self.cfg.migrate_pricing!r}; "
                            f"pick from ['modeled', 'constant']")
@@ -275,6 +278,7 @@ class HeroScheduler:
                                 beta=cfgn.beta if cfgn.enable_criticality
                                 else 0.0)                       # line 4
         for n in dag.ready():
+            self._ready_at.setdefault(n.id, now)
             if n.id not in self._fifo_seq:
                 self._fifo_seq[n.id] = self._seq
                 self._seq += 1
@@ -587,6 +591,16 @@ class HeroScheduler:
             # exactly the capacity left over after this pass
             self._preempt_pass(dag, decisions, now, idle)
         return decisions
+
+    def take_ready_time(self, node: Node) -> Optional[float]:
+        """The ``now`` of the pass that first saw ``node`` (or, for a
+        fused dispatch, its earliest member) ready since its last launch;
+        forgets it, so a node that returns to the pool (a cancelled
+        straggler, a decode-round boundary) waits from its return."""
+        ids = [node.id] + [m.id for m in node.payload.get("members", ())]
+        seen = [t for t in (self._ready_at.pop(i, None) for i in ids)
+                if t is not None]
+        return min(seen) if seen else None
 
     # -- SLO classes & preemption ------------------------------------------
     def _slo_class(self, node: Node) -> str:

@@ -15,9 +15,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core.events import (CT_LM_KV_BYTES_RESERVED, CT_LM_KV_BYTES_USED,
+                               CT_LM_PAD_ROWS, CT_LM_REAL_TOKENS, CT_LM_STEPS,
+                               SP_LM_CALL, SP_LM_FETCH, SP_LM_PREFILL,
+                               SP_LM_STEP)
 from repro.models import Model, build_model
 from repro.rag.embedder import CALL_WIDTH
 from repro.rag.tokenizer import EOS
+from repro.serving import spans
 
 
 @dataclass
@@ -44,6 +49,11 @@ class LMAgent:
         self.max_len = max_len
         self.model: Model = build_model(cfg)
         model = self.model
+        # bytes of one row's keys and values at one position, summed over
+        # the layers (a dense attention cache)
+        self._kv_slot_bytes = (cfg.num_layers * 2 * cfg.num_kv_heads
+                               * cfg.resolved_head_dim
+                               * jnp.dtype(cfg.dtype).itemsize)
 
         def prefill(params, tokens):
             cache = model.init_cache(tokens.shape[0], max_len)
@@ -64,14 +74,34 @@ class LMAgent:
         when ``stop_at_eos``."""
         n = len(prompts)
         rows = list(prompts) + [prompts[0]] * (CALL_WIDTH - n)
-        tok, cache = self._prefill(self.params, jnp.asarray(rows, jnp.int32))
-        outs = [[int(t)] for t in np.asarray(tok)[:n]]
-        for _ in range(max_new - 1):
-            if stop_at_eos and outs[0][-1] == EOS:
-                break
-            tok, cache = self._decode(self.params, tok, cache)
-            for seq, t in zip(outs, np.asarray(tok)[:n]):
-                seq.append(int(t))
+        steps = 0
+        with spans.span(SP_LM_CALL, role=self.cfg.name, width=CALL_WIDTH,
+                        rows=n):
+            with spans.span(SP_LM_PREFILL):
+                tok, cache = self._prefill(self.params,
+                                           jnp.asarray(rows, jnp.int32))
+                with spans.span(SP_LM_FETCH):
+                    host = np.asarray(tok)
+            outs = [[int(t)] for t in host[:n]]
+            for _ in range(max_new - 1):
+                if stop_at_eos and outs[0][-1] == EOS:
+                    break
+                with spans.span(SP_LM_STEP):
+                    tok, cache = self._decode(self.params, tok, cache)
+                    with spans.span(SP_LM_FETCH):
+                        host = np.asarray(tok)
+                for seq, t in zip(outs, host[:n]):
+                    seq.append(int(t))
+                steps += 1
+        if spans.enabled():
+            spans.count(CT_LM_STEPS, steps)
+            spans.count(CT_LM_REAL_TOKENS, n * (steps + 1))
+            spans.count(CT_LM_PAD_ROWS, CALL_WIDTH - n)
+            spans.count(CT_LM_KV_BYTES_RESERVED,
+                        self._kv_slot_bytes * CALL_WIDTH * self.max_len)
+            # the prefill writes the prompt's positions, each step one more
+            spans.count(CT_LM_KV_BYTES_USED,
+                        self._kv_slot_bytes * n * (len(rows[0]) + steps))
         return outs
 
     def generate(self, prompt_ids: Sequence[int], max_new: int = 32,

@@ -17,9 +17,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core.events import SP_EMBED_CALL, SP_RERANK_CALL
 from repro.models import layers as L
 from repro.models import lm
 from repro.rag.tokenizer import SEP
+from repro.serving import spans
 
 # batch width of every model call in the live pipeline: embed and rerank
 # micro-batches, LMAgent prefill/decode (agents.py) and the scheduler's
@@ -69,11 +71,12 @@ class Embedder:
 
     def embed(self, token_lists: Sequence[Sequence[int]]) -> np.ndarray:
         """-> (len(token_lists), d_model) L2-normalized embeddings."""
-        outs = [np.asarray(self._fn(self.params, tokens,
-                                    (tokens != 0).astype(np.float32)))[:n]
-                for n, tokens in _micro_batches(token_lists, self.max_tokens,
-                                                self.cfg.vocab_size)]
-        return np.concatenate(outs)
+        with spans.span(SP_EMBED_CALL, items=len(token_lists)):
+            outs = [np.asarray(self._fn(self.params, tokens,
+                                        (tokens != 0).astype(np.float32)))[:n]
+                    for n, tokens in _micro_batches(
+                        token_lists, self.max_tokens, self.cfg.vocab_size)]
+            return np.concatenate(outs)
 
 
 class Reranker:
@@ -94,7 +97,8 @@ class Reranker:
     def score(self, query_ids: Sequence[int],
               chunk_ids_list: Sequence[Sequence[int]]) -> np.ndarray:
         pairs = [list(query_ids) + [SEP] + list(c) for c in chunk_ids_list]
-        return np.concatenate([
-            np.asarray(self._fn(self.params, tokens))[:n]
-            for n, tokens in _micro_batches(pairs, self.max_tokens,
-                                            self.cfg.vocab_size)])
+        with spans.span(SP_RERANK_CALL, items=len(pairs)):
+            return np.concatenate([
+                np.asarray(self._fn(self.params, tokens))[:n]
+                for n, tokens in _micro_batches(pairs, self.max_tokens,
+                                                self.cfg.vocab_size)])

@@ -17,7 +17,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.events import (SP_VDB_ADD, SP_VDB_FETCH, SP_VDB_IDS,
+                               SP_VDB_KERNEL, SP_VDB_SEARCH)
 from repro.kernels import ops
+from repro.serving import spans
 
 
 _ADD_ROWS = 32     # rows per write program (one compile for any add size)
@@ -49,7 +52,7 @@ class VectorDB:
         """vectors (m, dim), L2-normalized by caller for cosine metric."""
         vectors = np.asarray(vectors, np.float32)
         m = vectors.shape[0]
-        with self._add_lock:
+        with spans.span(SP_VDB_ADD, rows=m), self._add_lock:
             if self._n + m > self.capacity:
                 raise RuntimeError("vector db capacity exceeded")
             vecs = self._vecs
@@ -75,9 +78,15 @@ class VectorDB:
         n = self._n
         if n == 0:
             raise RuntimeError("search on empty db")
-        vals, idxs = ops.topk_retrieval(queries, self._vecs, min(k, n), n,
-                                        use_pallas=use_pallas)
-        return np.asarray(vals), np.asarray(self._ids)[np.asarray(idxs)]
+        with spans.span(SP_VDB_SEARCH, rows=n):
+            with spans.span(SP_VDB_KERNEL):
+                vals, idxs = ops.topk_retrieval(queries, self._vecs,
+                                                min(k, n), n,
+                                                use_pallas=use_pallas)
+            with spans.span(SP_VDB_FETCH):
+                vals, idxs = np.asarray(vals), np.asarray(idxs)
+            with spans.span(SP_VDB_IDS):
+                return vals, np.asarray(self._ids)[idxs]
 
     def lowered_search(self, n_queries: int, k: int):
         """The search program for ``n_queries`` queries as it is lowered

@@ -25,14 +25,25 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 from repro.core.dag import DONE, READY, RUNNING, DynamicDAG, Node
-from repro.core.events import (EV_CANCELLED, EV_DONE, EV_KV_FETCH,
-                               EV_KV_MIGRATE, EV_PREEMPT, EV_RETRY,
-                               EV_START, EV_STRAGGLER, EV_TOKENS,
-                               SPILL_TIERS)
+from repro.core.events import (CT_EXECUTOR_CANCELLED_RUNS,
+                               CT_RUNTIME_PASSES, EV_CANCELLED, EV_DONE,
+                               EV_KV_FETCH, EV_KV_MIGRATE, EV_PREEMPT,
+                               EV_RETRY, EV_START, EV_STRAGGLER, EV_TOKENS,
+                               SP_EXECUTOR_QUEUE, SP_EXECUTOR_RUN,
+                               SP_RUNTIME_DISPATCH_PASS, SP_RUNTIME_HARVEST,
+                               SP_RUNTIME_POLL, SP_RUNTIME_READY_WAIT,
+                               SP_RUNTIME_RUN, SPILL_TIERS)
 from repro.core.partitioner import dispatch_passes, fused_boundary_index
 from repro.core.scheduler import Dispatch, HeroScheduler
+from repro.serving import spans
 
 StageFn = Callable[[Node, int], Any]   # (node, batch) -> result
+
+
+def _qids(node: Node) -> List[str]:
+    """The query namespaces (``q<n>``) a node, or its members, serve."""
+    ids = [m.id for m in node.payload.get("members", ())] or [node.id]
+    return sorted({i.partition("/")[0] for i in ids if "/" in i})
 
 
 @dataclass
@@ -40,6 +51,7 @@ class _Task:
     node: Node
     batch: int
     fn: StageFn
+    submitted: float = 0.0
     started: float = 0.0
     cancelled: bool = False
     result: Any = None
@@ -62,6 +74,7 @@ class PUExecutor:
         self._thread.start()
 
     def submit(self, task: _Task):
+        task.submitted = time.monotonic()
         with self._lock:
             self._working += 1
         self._q.put(task)
@@ -84,10 +97,21 @@ class PUExecutor:
                 return
             task.started = time.monotonic()
             if not task.cancelled:
-                try:
-                    task.result = task.fn(task.node, task.batch)
-                except Exception:                  # retry handled upstream
-                    task.error = traceback.format_exc()
+                node, qids = task.node, _qids(task.node)
+                spans.record(SP_EXECUTOR_QUEUE, task.submitted,
+                             task.started, qids, stage=node.stage,
+                             pu=self.name)
+                with spans.span(SP_EXECUTOR_RUN, qids, stage=node.stage,
+                                pu=self.name) as sp:
+                    try:
+                        task.result = task.fn(node, task.batch)
+                    except Exception:              # retry handled upstream
+                        task.error = traceback.format_exc()
+                    if sp is not None:
+                        sp.attrs["cancelled"] = task.cancelled
+                if task.cancelled:
+                    # a straggler copy that ran to its end all the same
+                    spans.count(CT_EXECUTOR_CANCELLED_RUNS)
             # release before signalling: once done_evt is visible the PU is
             # genuinely free, so a fresh dispatch must not see busy()==True
             with self._lock:
@@ -140,7 +164,14 @@ class HeroRuntime:
 
     def run(self, dag: DynamicDAG, poll: float = 0.002,
             timeout: float = 300.0) -> Dict[str, Any]:
-        t0 = time.monotonic()
+        # the span's start is the timeline's epoch: an event at run-relative
+        # t happened at monotonic time t0 + t
+        with spans.span(SP_RUNTIME_RUN) as sp:
+            return self._run(dag, poll, timeout,
+                             sp.t0 if sp is not None else time.monotonic())
+
+    def _run(self, dag: DynamicDAG, poll: float, timeout: float,
+             t0: float) -> Dict[str, Any]:
         self._t0 = t0   # run-relative epoch, readable by stage fns (timers)
         inflight: Dict[str, tuple] = {}     # node id -> (_Task, Dispatch, retries)
 
@@ -170,6 +201,11 @@ class HeroRuntime:
             return sum(d.bandwidth for _, d, _ in inflight.values())
 
         def dispatch():
+            spans.count(CT_RUNTIME_PASSES)
+            with spans.span(SP_RUNTIME_DISPATCH_PASS):
+                schedule()
+
+        def schedule():
             if dag._cancel_pending:
                 # user-requested cancellation, observed at the same
                 # granularity as the simulator: queued nodes collapse,
@@ -221,7 +257,8 @@ class HeroRuntime:
                         # cancelled stragglers are non-preemptible: the PU
                         # drains them off-book (not in inflight) and only
                         # then frees up — waiting is progress, not deadlock
-                        time.sleep(poll)
+                        with spans.span(SP_RUNTIME_POLL):
+                            time.sleep(poll)
                         continue
                     raise RuntimeError(
                         f"deadlock: {[n.id for n in dag.unfinished()][:4]}")
@@ -259,36 +296,42 @@ class HeroRuntime:
                     progressed = True
                     if task.cancelled:
                         continue
-                    if task.error is not None:
-                        if retries < self.max_retries:
-                            self._emit(now(), EV_RETRY, d.node)
-                            self._launch(d, inflight, dag,
-                                         retries=retries + 1, now_t=now())
-                            continue
-                        raise RuntimeError(
-                            f"stage {nid} failed:\n{task.error}")
-                    if d.node.payload.get("decode_round"):
-                        # synthetic per-boundary id: storing under it would
-                        # leak one entry per round — fan a coalesce-aware
-                        # fn's {member id: result} dict out per query
-                        # instead (each member accumulates its rounds)
-                        per = (task.result
-                               if isinstance(task.result, dict) else {})
-                        for m in d.node.payload["members"]:
-                            if m.id in per:
-                                self.results.setdefault(m.id, []).append(
-                                    per[m.id])
-                    elif not d.node.payload.get("draft_round"):
-                        # draft sub-dispatches get a fresh id per round —
-                        # storing their (candidate-token) results would
-                        # leak one entry per round; the verify fn is the
-                        # one that owes the stream its accepted output
-                        self.results[nid] = task.result
-                    prog = d.node.payload.get("on_progress")
-                    dag.mark_done(nid, now())
-                    if prog is not None and d.node.kind == "stream_decode":
-                        prog(dag, d.node, d.node.workload)
-                    self._emit(now(), EV_DONE, d.node)
+                    with spans.span(SP_RUNTIME_HARVEST, _qids(d.node),
+                                    stage=d.node.stage):
+                        if task.error is not None:
+                            if retries < self.max_retries:
+                                self._emit(now(), EV_RETRY, d.node)
+                                self._launch(d, inflight, dag,
+                                             retries=retries + 1,
+                                             now_t=now())
+                                continue
+                            raise RuntimeError(
+                                f"stage {nid} failed:\n{task.error}")
+                        if d.node.payload.get("decode_round"):
+                            # synthetic per-boundary id: storing under it
+                            # would leak one entry per round — fan a
+                            # coalesce-aware fn's {member id: result} dict
+                            # out per query instead (each member
+                            # accumulates its rounds)
+                            per = (task.result
+                                   if isinstance(task.result, dict) else {})
+                            for m in d.node.payload["members"]:
+                                if m.id in per:
+                                    self.results.setdefault(
+                                        m.id, []).append(per[m.id])
+                        elif not d.node.payload.get("draft_round"):
+                            # draft sub-dispatches get a fresh id per round
+                            # — storing their (candidate-token) results
+                            # would leak one entry per round; the verify fn
+                            # is the one that owes the stream its accepted
+                            # output
+                            self.results[nid] = task.result
+                        prog = d.node.payload.get("on_progress")
+                        dag.mark_done(nid, now())
+                        if (prog is not None
+                                and d.node.kind == "stream_decode"):
+                            prog(dag, d.node, d.node.workload)
+                        self._emit(now(), EV_DONE, d.node)
                 elif task.started and not task.cancelled:
                     # straggler heartbeat (perf-model ETA as the prior, with
                     # a jitter floor and a per-node speculation cap)
@@ -316,11 +359,13 @@ class HeroRuntime:
             if progressed:
                 dispatch()
             else:
-                time.sleep(poll)
+                with spans.span(SP_RUNTIME_POLL):
+                    time.sleep(poll)
         return self.results
 
     def _launch(self, d: Dispatch, inflight, dag: DynamicDAG, retries: int,
                 now_t: float = 0.0):
+        t_ready = self.sched.take_ready_time(d.node)
         fn = self.stage_fns.get(d.node.stage)
         if d.pu == "io" or fn is None:
             fn = self.stage_fns.get("__io__", lambda n, b: None)
@@ -362,4 +407,8 @@ class HeroRuntime:
         else:
             self.executors[d.pu].submit(task)
         inflight[d.node.id] = (task, d, retries)
+        if t_ready is not None and spans.enabled():
+            spans.record(SP_RUNTIME_READY_WAIT, self._t0 + t_ready,
+                         time.monotonic(), _qids(d.node),
+                         stage=d.node.stage, pu=d.pu)
         self._emit(now_t, EV_START, d.node)

@@ -76,6 +76,63 @@ def test_evt_rules_only_apply_to_event_modules():
                                 "src/repro/rag/workflow.py") == []
 
 
+# --- EVT001 / EVT002 on span and counter names --------------------------------
+
+EXE = "src/repro/serving/executor.py"     # an event module that records spans
+
+
+def _span(src, key="serving/executor.py", path=EXE):
+    return check_event_literals(ast.parse(src), key, path)
+
+
+def test_evt001_raw_span_name():
+    vs = _span("with spans.span('runtime.poll'):\n    pass")
+    assert _rules(vs) == ["EVT001"]
+
+
+def test_evt001_misspelled_span_name_in_a_recording_module():
+    vs = _span("spans.record('lm.stp', t0, t1)", key="rag/agents.py",
+               path="src/repro/rag/agents.py")
+    assert _rules(vs) == ["EVT001"]
+    assert "'lm.step'" in vs[0].message
+
+
+def test_evt001_raw_counter_name():
+    vs = _span("spans.count('runtime.passes')", key="api/session.py",
+               path="src/repro/api/session.py")
+    assert _rules(vs) == ["EVT001"]
+
+
+def test_evt001_constant_of_the_wrong_kind():
+    assert _rules(_span("spans.count(SP_LM_STEP, 3)")) == ["EVT001"]
+    assert _rules(_span("spans.span(CT_LM_STEPS)")) == ["EVT001"]
+
+
+def test_evt001_comparison_against_raw_span_name():
+    vs = _span("if s.name == 'lm.fetch':\n    pass")
+    assert _rules(vs) == ["EVT001"]
+
+
+def test_evt002_span_name_typo_flagged():
+    vs = _span("if s.name == 'runtime.pol':\n    pass")
+    assert _rules(vs) == ["EVT002"]
+
+
+def test_span_negative_constants_and_other_counts_are_clean():
+    vs = _span("with spans.span(SP_RUNTIME_POLL, qids, stage=s):\n"
+               "    pass\n"
+               "spans.record(SP_RUNTIME_READY_WAIT, t0, t1)\n"
+               "spans.count(CT_RUNTIME_PASSES)\n"
+               "n = ids.count('q1')\n")
+    assert vs == []
+
+
+def test_span_rules_only_apply_to_recording_modules():
+    vs = check_event_literals(ast.parse("spans.span('lm.stpe')"),
+                              "rag/chunker.py", "src/repro/rag/chunker.py")
+    assert vs == []
+
+
 # --- CFG001 / CFG002 ---------------------------------------------------------
 
 def _cfg(sched_src, extra=None):
