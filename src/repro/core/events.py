@@ -74,8 +74,9 @@ SP_RUNTIME_READY_WAIT = "runtime.ready_wait"  # first seen ready -> launch
 SP_EXECUTOR_QUEUE = "executor.queue"      # submit -> worker start
 SP_EXECUTOR_RUN = "executor.run"          # a stage fn on its PU's worker
 SP_LM_CALL = "lm.call"                    # one width-CALL_WIDTH agent call
-SP_LM_PREFILL = "lm.prefill"
-SP_LM_STEP = "lm.step"                    # one decode step
+SP_LM_STEP = "lm.step"                    # the call's device work: the
+#                                           prefill and every decode step
+#                                           enqueued, then the wait
 SP_LM_FETCH = "lm.fetch"                  # device -> host of the tokens
 SP_VDB_SEARCH = "vdb.search"
 SP_VDB_KERNEL = "vdb.kernel"              # the top-k program's enqueue
@@ -88,8 +89,8 @@ SP_RERANK_CALL = "rerank.call"
 ALL_SPANS = frozenset({
     SP_SESSION_BUILD, SP_RUNTIME_RUN, SP_RUNTIME_DISPATCH_PASS,
     SP_RUNTIME_HARVEST, SP_RUNTIME_POLL, SP_RUNTIME_READY_WAIT,
-    SP_EXECUTOR_QUEUE, SP_EXECUTOR_RUN, SP_LM_CALL, SP_LM_PREFILL,
-    SP_LM_STEP, SP_LM_FETCH, SP_VDB_SEARCH, SP_VDB_KERNEL, SP_VDB_FETCH,
+    SP_EXECUTOR_QUEUE, SP_EXECUTOR_RUN, SP_LM_CALL, SP_LM_STEP,
+    SP_LM_FETCH, SP_VDB_SEARCH, SP_VDB_KERNEL, SP_VDB_FETCH,
     SP_VDB_IDS, SP_VDB_ADD, SP_EMBED_CALL, SP_RERANK_CALL,
 })
 
@@ -97,14 +98,16 @@ ALL_SPANS = frozenset({
 CT_RUNTIME_PASSES = "runtime.passes"
 CT_EXECUTOR_CANCELLED_RUNS = "executor.cancelled_runs"  # ran to the end
 #                                                         after a cancel
-CT_LM_STEPS = "lm.steps"
+CT_LM_STEPS = "lm.steps"                  # decode steps whose tokens
+#                                           the call returns
+CT_LM_FETCHES = "lm.fetches"              # device -> host syncs per call
 CT_LM_REAL_TOKENS = "lm.real_tokens"      # tokens returned to real rows
 CT_LM_PAD_ROWS = "lm.pad_rows"            # padding rows per call
-CT_LM_KV_BYTES_RESERVED = "lm.kv_bytes_reserved"
-CT_LM_KV_BYTES_USED = "lm.kv_bytes_used"
+CT_LM_KV_BYTES_RESERVED = "lm.kv_bytes_reserved"  # the call's cache
+CT_LM_KV_BYTES_USED = "lm.kv_bytes_used"  # positions the call wrote
 
 ALL_COUNTERS = frozenset({
     CT_RUNTIME_PASSES, CT_EXECUTOR_CANCELLED_RUNS, CT_LM_STEPS,
-    CT_LM_REAL_TOKENS, CT_LM_PAD_ROWS, CT_LM_KV_BYTES_RESERVED,
-    CT_LM_KV_BYTES_USED,
+    CT_LM_FETCHES, CT_LM_REAL_TOKENS, CT_LM_PAD_ROWS,
+    CT_LM_KV_BYTES_RESERVED, CT_LM_KV_BYTES_USED,
 })

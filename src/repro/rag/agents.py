@@ -15,10 +15,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core.events import (CT_LM_KV_BYTES_RESERVED, CT_LM_KV_BYTES_USED,
-                               CT_LM_PAD_ROWS, CT_LM_REAL_TOKENS, CT_LM_STEPS,
-                               SP_LM_CALL, SP_LM_FETCH, SP_LM_PREFILL,
-                               SP_LM_STEP)
+from repro.core.events import (CT_LM_FETCHES, CT_LM_KV_BYTES_RESERVED,
+                               CT_LM_KV_BYTES_USED, CT_LM_PAD_ROWS,
+                               CT_LM_REAL_TOKENS, CT_LM_STEPS, SP_LM_CALL,
+                               SP_LM_FETCH, SP_LM_STEP)
 from repro.models import Model, build_model
 from repro.rag.embedder import CALL_WIDTH
 from repro.rag.tokenizer import EOS
@@ -33,7 +33,8 @@ class GenResult:
 
 class LMAgent:
     """Greedy decoding agent: jitted prefill, then jitted stepwise decode
-    through the KV cache.
+    through the KV cache, every step enqueued before the host reads a
+    token, so a call syncs with the device once, at its end.
 
     Every call runs at one batch width (``CALL_WIDTH`` streams): fewer
     streams are padded with copies of the first prompt, more are split
@@ -41,7 +42,12 @@ class LMAgent:
     exactly one prefill and one decode program per prompt length, and a
     warm agent compiles nothing.  The serving path caps decode rounds at
     ``CALL_WIDTH`` members (``decode_batch_cap``), so one fused dispatch
-    is one call there."""
+    is one call there.
+
+    The steps are separate programs, not one on-device loop: a program
+    that runs the layer stack more than once (a prefill and a decode
+    loop) makes the TPU compiler copy the q/k/v weight stacks into
+    another layout on every call, 1.13 GB for the 4B chat model."""
 
     def __init__(self, cfg: ModelConfig, params, max_len: int = 512):
         self.cfg = cfg
@@ -69,31 +75,30 @@ class LMAgent:
 
     def _run(self, prompts: Sequence[Sequence[int]], max_new: int,
              stop_at_eos: bool) -> List[List[int]]:
-        """One width-``CALL_WIDTH`` prefill + decode loop over at most
-        ``CALL_WIDTH`` prompts; stops early at EOS of the first stream only
-        when ``stop_at_eos``."""
+        """One width-``CALL_WIDTH`` prefill + ``max_new - 1`` decode steps
+        over at most ``CALL_WIDTH`` prompts, then one fetch; when
+        ``stop_at_eos``, every stream ends where the first one emits EOS
+        (the steps after it run on the device and are dropped)."""
         n = len(prompts)
         rows = list(prompts) + [prompts[0]] * (CALL_WIDTH - n)
-        steps = 0
         with spans.span(SP_LM_CALL, role=self.cfg.name, width=CALL_WIDTH,
                         rows=n):
-            with spans.span(SP_LM_PREFILL):
+            with spans.span(SP_LM_STEP):
                 tok, cache = self._prefill(self.params,
                                            jnp.asarray(rows, jnp.int32))
-                with spans.span(SP_LM_FETCH):
-                    host = np.asarray(tok)
-            outs = [[int(t)] for t in host[:n]]
-            for _ in range(max_new - 1):
-                if stop_at_eos and outs[0][-1] == EOS:
-                    break
-                with spans.span(SP_LM_STEP):
+                toks = [tok]
+                for _ in range(max_new - 1):
                     tok, cache = self._decode(self.params, tok, cache)
-                    with spans.span(SP_LM_FETCH):
-                        host = np.asarray(tok)
-                for seq, t in zip(outs, host[:n]):
-                    seq.append(int(t))
-                steps += 1
+                    toks.append(tok)
+                with spans.span(SP_LM_FETCH):
+                    host = np.stack(jax.device_get(toks), axis=1)[:n]
+        if stop_at_eos:
+            eos = np.flatnonzero(host[0] == EOS)
+            if eos.size:
+                host = host[:, :eos[0] + 1]
+        steps = host.shape[1] - 1
         if spans.enabled():
+            spans.count(CT_LM_FETCHES)
             spans.count(CT_LM_STEPS, steps)
             spans.count(CT_LM_REAL_TOKENS, n * (steps + 1))
             spans.count(CT_LM_PAD_ROWS, CALL_WIDTH - n)
@@ -102,7 +107,7 @@ class LMAgent:
             # the prefill writes the prompt's positions, each step one more
             spans.count(CT_LM_KV_BYTES_USED,
                         self._kv_slot_bytes * n * (len(rows[0]) + steps))
-        return outs
+        return host.tolist()
 
     def generate(self, prompt_ids: Sequence[int], max_new: int = 32,
                  stop_at_eos: bool = True) -> GenResult:

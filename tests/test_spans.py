@@ -7,7 +7,8 @@ import jax
 import pytest
 
 from repro.api import HeroSession, SessionOptions
-from repro.core.events import (ALL_SPANS, CT_LM_KV_BYTES_RESERVED,
+from repro.core.events import (ALL_SPANS, CT_LM_FETCHES,
+                               CT_LM_KV_BYTES_RESERVED,
                                CT_LM_KV_BYTES_USED, CT_LM_STEPS,
                                CT_RUNTIME_PASSES, SP_EXECUTOR_RUN,
                                SP_LM_CALL, SP_LM_STEP,
@@ -204,8 +205,10 @@ def test_live_w2_records_every_span_for_every_query():
     # a node is first seen ready by a pass of the run, and launched later
     waits = [s for s in recorded if s.name == SP_RUNTIME_READY_WAIT]
     assert waits and all(run.t0 <= s.t0 <= s.t1 <= run.t1 for s in waits)
+    # one fetch per LM call, whatever its steps
+    calls = [s for s in recorded if s.name == SP_LM_CALL]
     steps = [s for s in recorded if s.name == SP_LM_STEP]
-    assert counters[CT_LM_STEPS] == len(steps)
+    assert counters[CT_LM_FETCHES] == len(steps) == len(calls)
     assert counters[CT_RUNTIME_PASSES] == sum(
         1 for s in recorded if s.name == SP_RUNTIME_DISPATCH_PASS)
     assert 0 < counters[CT_LM_KV_BYTES_USED] < counters[

@@ -93,3 +93,28 @@ def test_int8_matmul_compiles_for_v5e(one_chip):
                    _sds((K, N), jnp.int8, one_chip),
                    _sds((M, 1), jnp.float32, one_chip),
                    _sds((1, N), jnp.float32, one_chip))
+
+
+@pytest.mark.parametrize("role,max_len", [("chat", 512), ("search", 256)])
+def test_lm_programs_copy_no_weight_stack_for_v5e(one_chip, role, max_len):
+    """An agent's prefill and decode programs at published widths: each
+    runs the layer stack once, and its scratch memory holds no whole q/k/v
+    weight stack.  (One program that ran the stack in several loops, a
+    prefill and a decode loop, would copy the stacks into another layout
+    on every call: 1.13 GB for the 4B.)"""
+    from repro.rag.agents import LMAgent
+    from repro.rag.embedder import CALL_WIDTH
+
+    agent = LMAgent(get_family("qwen3")[role], None, max_len=max_len)
+    params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                          jax.eval_shape(agent.model.init,
+                                         jax.random.PRNGKey(0)))
+    cache = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                         jax.eval_shape(lambda: agent.model.init_cache(
+                             CALL_WIDTH, max_len)))
+    wk = params["blocks"]["attn"]["wk"]
+    for program, args in (
+            (agent._prefill, (_sds((CALL_WIDTH, 16), "int32", one_chip),)),
+            (agent._decode, (_sds((CALL_WIDTH,), "int32", one_chip), cache))):
+        memory = program.lower(params, *args).compile().memory_analysis()
+        assert memory.temp_size_in_bytes < wk.size * wk.dtype.itemsize
