@@ -5,10 +5,11 @@
 
 For each seed, in this one process: one run of the cell at its own load
 (set-up, a window of ``--seconds``, the program's numbers as the benchmark
-compares them), then the control on the same recorded inputs: the
-reference computed in fp8 e4m3 (weights per output channel, matmul
-inputs per token) in the program's place, and the store search with the
-store and the query rounded to e4m3 per row.  One JSON line per seed: ``program`` and
+compares them), then the control on the same recorded inputs: each
+model's reference (the module its configuration entry names) computed in
+fp8 e4m3 (weights per output channel, matmul inputs per token) in the
+program's place, and the store search with the store and the query
+rounded to e4m3 per row.  One JSON line per seed: ``program`` and
 ``control`` readings by number.  The benchmark's own runs never run this.
 """
 from __future__ import annotations
@@ -69,28 +70,27 @@ def altered_token_gap(ref, calls) -> float:
 def control_numbers(cfg: dict, seed: int, rec, store) -> dict:
     """The control's reading of each number compared, with its parts."""
     import checks
-    import reference
+    from harness import make_reference
 
     models, api = cfg["models"], cfg["api"]
     out = {}
     for role in cfg["generating_roles"]:
-        ref = reference.Reference(models[role], seed)
-        ctl = reference.Reference(models[role], seed, quant="fp8")
+        ref = make_reference(models[role], seed)
+        ctl = make_reference(models[role], seed, quant="fp8")
         calls = rec.lm.get(role, [])
         out[f"{role}_logit_gap"] = checks.control_lm_gap(ref, ctl, calls)
         out[f"{role}_logit_gap.token_altered"] = altered_token_gap(ref,
                                                                    calls)
     distinct, _ = checks.embed_inputs(rec.embed, api["embed_max_tokens"])
-    r = reference.Reference(models["embed"], seed).embed(distinct)
-    c = reference.Reference(models["embed"], seed, quant="fp8").embed(
-        distinct)
+    r = make_reference(models["embed"], seed).embed(distinct)
+    c = make_reference(models["embed"], seed, quant="fp8").embed(distinct)
     out["embed_err"] = float(np.abs(r - c).max())
     _, distinct, _ = checks.rerank_pairs(rec.rerank, api["sep_token"],
                                          api["rerank_max_tokens"])
-    rs, scale = reference.Reference(models["rerank"], seed).rerank(
+    rs, scale = make_reference(models["rerank"], seed).rerank(
         distinct, api["sep_token"])
-    cs, _ = reference.Reference(models["rerank"], seed, quant="fp8") \
-        .rerank(distinct, api["sep_token"])
+    cs, _ = make_reference(models["rerank"], seed, quant="fp8").rerank(
+        distinct, api["sep_token"])
     out["rerank_err"] = float((np.abs(rs - cs) / scale).max())
     out["vsearch_err"] = fp8_search(store, rec.search)
     out["decode_logit_gap"] = max(out[f"{role}_logit_gap"]

@@ -3,16 +3,19 @@ and the comparison with the reference.
 
 Everything that belongs to a configuration, a traffic mix or a metric is
 found by name: ``configs/<config>.json``, ``traffic/<traffic>.json`` and
-``metrics/<metric>.py``, as ``BENCHMARK.json`` names them.  This module
-drives the program only through its entry points (``build_pipeline``,
-``stage_fns``, ``HeroSession``), and records spans and model calls by
-wrapping what those return.
+``metrics/<metric>.py``, as ``BENCHMARK.json`` names them; a model entry
+of a configuration names the module of its plain reference
+(``reference_module``).  This module drives the program only through its
+entry points (``build_pipeline``, ``stage_fns``, ``HeroSession``), records
+spans and model calls by wrapping what those return, and in a traced run
+turns on the program's own span recorder (``repro.serving.spans``).
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import gc
+import importlib
 import importlib.util
 import json
 import shutil
@@ -28,14 +31,18 @@ import numpy as np
 
 import checks
 import traffic
-import reference as ref_mod
 import peaks
+import span_reduce
 import trace_reduce
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parents[1]
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 TRACE_SECONDS = 10.0
+# keys of a model entry that say how the benchmark uses the model, not
+# what the program builds; every other key is a width checked at set-up
+MODEL_META = frozenset({"source", "role_index", "init", "params",
+                        "reference"})
 
 
 # -- the cell, as BENCHMARK.json and its files describe it ------------------
@@ -80,6 +87,51 @@ def reader(metric: str) -> Callable:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def reference_module(m: dict):
+    """The module of model entry ``m``'s plain reference: the module of
+    this directory that its ``reference`` key names (dotted below a
+    subdirectory), ``reference.py`` where it names none.  It exports
+    ``Reference(m, seed, quant=None)`` with ``.logits(seqs)`` (and
+    ``.embed``/``.rerank`` for those roles), and may export
+    ``decode_flops(m, tokens)``."""
+    mod = importlib.import_module(m.get("reference", "reference"))
+    if not Path(mod.__file__).resolve().is_relative_to(BENCH):
+        raise ValueError(f"reference {m['reference']!r} is not a module "
+                         f"of {BENCH}")
+    return mod
+
+
+def make_reference(m: dict, seed: int, quant: Optional[str] = None):
+    """Model entry ``m``'s plain reference, its weights drawn from
+    ``seed``."""
+    return reference_module(m).Reference(m, seed, quant)
+
+
+def check_widths(role: str, stated: dict, built, prefix: str = ""):
+    """Stop set-up where the program builds another model than the entry
+    ``stated`` describes: each key that names a field of the built config
+    is compared, a dict against a sub-config (``moe``, ``mla``, ...) key
+    by key, and a key that is neither such a field nor ``MODEL_META``
+    stops set-up with its name, so a misspelt width cannot pass."""
+    fields = {f.name for f in dataclasses.fields(built)}
+    for key, want in stated.items():
+        name = prefix + key
+        if not prefix and key in MODEL_META:
+            continue
+        if key not in fields:
+            raise RuntimeError(f"{role}: the configuration states {name}, "
+                               f"which is no field of the program's "
+                               f"{type(built).__name__} and no benchmark "
+                               f"metadata")
+        got = getattr(built, key)
+        if dataclasses.is_dataclass(got) and isinstance(want, dict):
+            check_widths(role, want, got, name + ".")
+        elif (list(got) if isinstance(got, tuple) else got) != want:
+            raise RuntimeError(f"{role}: the program builds {name}="
+                               f"{got!r}, the configuration states "
+                               f"{want!r}")
 
 
 # -- recording ---------------------------------------------------------------
@@ -227,15 +279,20 @@ def wrap_stage_fns(fns: dict, rec: Recorder, db, annotate: bool) -> dict:
 
 class Tracer:
     """Profiler trace of a few seconds in the middle of the window,
-    started and stopped from timer threads."""
+    started and stopped from timer threads.  ``finish`` keeps the device
+    op events (``devices``) and, where ``hero``, the program's ``hero:``
+    span annotations (``hero``) for the span readers."""
 
-    def __init__(self, seconds: float):
+    def __init__(self, seconds: float, hero: bool):
         self.length = min(TRACE_SECONDS, max(1.0, 0.4 * seconds))
         self.offset = max(0.0, (seconds - self.length) / 2)
         self.lock = threading.Lock()
         self.dir: Optional[str] = None
         self.t0 = self.t1 = None
         self.timers: List[threading.Timer] = []
+        self.want_hero = hero
+        self.devices: Dict[str, list] = {}
+        self.hero: List[list] = []
 
     def arm(self):
         self.timers = [threading.Timer(self.offset, self.start),
@@ -271,6 +328,9 @@ class Tracer:
             if not files:
                 return None
             events = trace_reduce.load(str(files[-1]))
+            self.devices = events["devices"]
+            if self.want_hero:
+                self.hero = span_reduce.load_hero(str(files[-1]))
             for line in trace_reduce.summary(events):
                 print(line, file=sys.stderr)
             return trace_reduce.reduce(events, self.t1 - self.t0)
@@ -302,11 +362,16 @@ def fill_store(db, n_rows: int, seed: int, chunk: int = 32) -> np.ndarray:
 
 def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         t_start: float, device, pipe_hook: Optional[Callable] = None,
-        keep: Optional[dict] = None) -> dict:
+        keep: Optional[dict] = None,
+        record_spans: Optional[bool] = None) -> dict:
     """Set-up, window, readers and comparison; -> the result line.
     ``pipe_hook(pipe)`` runs on the built pipeline before the stage fns
     are made (tests break the timed path there); ``keep``, if given,
-    receives what the comparison read (the control re-reads it)."""
+    receives what the comparison read (the control re-reads it) and the
+    readers' ``ctx``.  The program's span recorder is on, annotating the
+    profiler trace, from the warm-up to the end of the window where
+    ``record_spans`` (by default: where ``trace``), and off otherwise;
+    ``spanrun.py`` sets it apart from ``trace`` to measure its cost."""
     import jax
     from jax import monitoring
 
@@ -314,8 +379,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     from repro.launch import serve
     from repro.launch.compile_cache import enable_compile_cache
     from repro.rag import default_means
+    from repro.serving import spans
 
     cfg, mix = cell.config, cell.mix
+    record = trace if record_spans is None else record_spans
     compiles = [0]
 
     def on_event(event, secs, **kw):
@@ -332,14 +399,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     pipe = serve.build_pipeline(seed=seed, **cfg["build"])
     jax.block_until_ready(pipe.models)
     for role, m in cfg["models"].items():
-        mc = pipe.models[role][0]
-        for key in ("num_layers", "d_model", "num_heads", "num_kv_heads",
-                    "head_dim", "d_ff", "vocab_size", "tie_embeddings",
-                    "dtype", "rope_theta", "norm_eps"):
-            if getattr(mc, key) != m[key]:
-                raise RuntimeError(f"{role}: the program builds {key}="
-                                   f"{getattr(mc, key)!r}, the "
-                                   f"configuration states {m[key]!r}")
+        check_widths(role, m, pipe.models[role][0])
     headroom = traffic.chunk_rows(queries + warm)
     n0 = len(pipe.db)
     n_fill = pipe.db.capacity - n0 - headroom
@@ -349,6 +409,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     filler = fill_store(pipe.db, n_fill, seed)
     if pipe_hook is not None:
         pipe_hook(pipe)
+    if record:
+        spans.enable(annotate=True)
+    else:
+        spans.disable()
+    spans.clear()
     rec = Recorder()
     instrument(pipe, rec, cfg["generating_roles"])
     fns = wrap_stage_fns(serve.stage_fns(pipe), rec, pipe.db, trace)
@@ -365,6 +430,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     jax.block_until_ready(pipe.models)
     setup_s = time.monotonic() - t_start
     rec.clear()
+    spans.clear()
     # the store's element size as the search program takes it
     store_itemsize = jax.tree.leaves(
         pipe.db.lowered_search(1, 1).args_info)[1].dtype.itemsize
@@ -381,7 +447,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
             r.tokens += int(tokens)
         return cb
 
-    tracer = Tracer(seconds) if trace else None
+    tracer = Tracer(seconds, record) if trace else None
     events: list = []
     c0 = compiles[0]
     t0 = time.monotonic()
@@ -416,6 +482,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
                                f"{len(queries)} queries inside the window")
     jax.block_until_ready(pipe.models)
     t1 = time.monotonic()
+    recorded, counters = spans.drain()
+    spans.disable()
     window_compiles = compiles[0] - c0
     reduced = tracer.finish() if tracer else None
     stats = device.memory_stats() or {}
@@ -427,7 +495,15 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
                          if e[1] in (EV_STRAGGLER, EV_RETRY)),
         window_compiles=window_compiles,
         trace=reduced, trace_window=(tracer.t0, tracer.t1) if tracer
-        else None, config=cfg, store_dim=pipe.db.dim,
+        else None, window=(t0, t1),
+        # the program's own spans and counters; None with the recorder off
+        program_spans=recorded if record else None,
+        counters=counters if record else None,
+        devices=tracer.devices if tracer else {},
+        hero=tracer.hero if tracer else [],
+        clock=(span_reduce.clock(tracer.hero, recorded) if tracer
+               else None),
+        config=cfg, store_dim=pipe.db.dim,
         store_itemsize=store_itemsize,
         # no peak, and so no share of one, off the chip (CPU rehearsals)
         peaks=(peaks.peaks(device.device_kind) if device.platform == "tpu"
@@ -448,7 +524,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         print(f"part {name}: {v!r}", file=sys.stderr)
     if keep is not None:
         keep.update(rec=rec, store=store, queries=due, window=(t0, t1),
-                    parts=parts)
+                    parts=parts, ctx=ctx)
     numbers["unanswered"] = sum(1 for r in due if not r.answered)
     numbers["missing_outputs"] = rec.missing
     correct, table = checks.judge(numbers, cfg["limits"])
@@ -475,15 +551,15 @@ def compare(cfg: dict, seed: int, rec: Recorder, store: np.ndarray,
     models, api = cfg["models"], cfg["api"]
     parts: Dict[str, float] = {}
     for role in cfg["generating_roles"]:
-        ref = ref_mod.Reference(models[role], seed)
+        ref = make_reference(models[role], seed)
         parts[f"{role}_logit_gap"] = checks.lm_gap(ref, rec.lm.get(role, []))
         del ref
-    ref = ref_mod.Reference(models["embed"], seed)
+    ref = make_reference(models["embed"], seed)
     distinct, index = checks.embed_inputs(rec.embed, api["embed_max_tokens"])
     parts["embed_err"] = checks.embed_err(
         ref.embed(distinct), index, rec.embed, api["embed_max_tokens"])
     del ref
-    ref = ref_mod.Reference(models["rerank"], seed)
+    ref = make_reference(models["rerank"], seed)
     pairs, distinct, index = checks.rerank_pairs(
         rec.rerank, api["sep_token"], api["rerank_max_tokens"])
     scores, scales = ref.rerank(distinct, api["sep_token"])

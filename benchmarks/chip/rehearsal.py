@@ -7,6 +7,7 @@ from __future__ import annotations
 import copy
 import functools
 import time
+from typing import Optional
 
 import harness
 
@@ -21,9 +22,12 @@ REDUCED = {"num_layers": 2, "d_model": 64, "num_heads": 4,
 LIMITS = {"decode_logit_gap": 1e-3, "retrieval_err": 1e-5}
 
 
-def reduced_cell(name: str, **mix) -> harness.Cell:
+def reduced_cell(name: str, config: Optional[dict] = None,
+                 **mix) -> harness.Cell:
+    """Cell ``name`` at reduced widths, under ``config`` in place of its
+    own configuration where given, with ``mix`` over its traffic."""
     cell = harness.load_cell(name)
-    cfg = copy.deepcopy(cell.config)
+    cfg = copy.deepcopy(config or cell.config)
     cfg["build"] = dict(cfg["build"], reduced_widths=True)
     cfg["limits"] = dict(cfg["limits"], **LIMITS)
     for m in cfg["models"].values():

@@ -6,16 +6,16 @@
 
 For each seed, in this one process, one run of the cell as ``run.py``
 makes it (``harness.run``), with the program's span recorder
-(``repro.serving.spans``) on from the end of set-up, annotating the
-profiler trace, and cleared where the measured window starts
-(``--spans on``); or off, as ``run.py`` runs (``--spans off``); or both,
-the pairs in turns (off, on; on, off; ...), so the recorder's cost shows
-in the end-to-end metrics of ``--trace 0`` runs.  After a run with the
-recorder on, ``span_reduce`` reads the spans against the profiler trace
-(``--trace 1``): the clock offset, the device's idle time by the program
-span open, and the per-layer numbers the spans give.  Tables go to
-standard error, one JSON line per run to standard output (and to
-``--out``).  The benchmark's own runs never run this.
+(``repro.serving.spans``) on, as ``run.py --trace 1`` runs it
+(``--spans on``); or off, as ``run.py --trace 0`` runs it
+(``--spans off``); or both, the pairs in turns (off, on; on, off; ...),
+so the recorder's cost shows: in the end-to-end metrics with
+``--trace 0``, in the per-layer ones with ``--trace 1``.  After a run
+with the recorder on, ``span_reduce`` also reads the spans against the
+profiler trace (``--trace 1``): the clock offset and the device's idle
+time by the program span open.  Tables go to standard error, one JSON
+line per run to standard output (and to ``--out``).  The benchmark's own
+runs never run this.
 """
 from __future__ import annotations
 
@@ -24,7 +24,6 @@ import json
 import sys
 import time
 from pathlib import Path
-from typing import Optional
 
 BENCH = Path(__file__).resolve().parent
 
@@ -35,70 +34,20 @@ def run_one(cell, seed: int, seconds: float, trace: bool, record: bool,
     under ``spans`` when ``record``."""
     import harness
     import span_reduce
-    import trace_reduce
-    from repro.serving import spans
 
-    class SpanRecorder(harness.Recorder):
-        """Clearing the harness's records (at the end of set-up and where
-        the window starts) also clears the program's spans."""
-
-        def clear(self):
-            super().clear()
-            if record:
-                spans.enable(annotate=True)
-            spans.clear()
-
-    class KeepingTracer(harness.Tracer):
-        """``harness.Tracer`` that keeps, as it reads the trace and before
-        the trace's files go, the device's op events and the program's
-        ``hero:`` annotations."""
-
-        last: Optional["KeepingTracer"] = None
-
-        def __init__(self, seconds):
-            super().__init__(seconds)
-            self.kept = {"devices": {}, "hero": []}
-            KeepingTracer.last = self
-
-        def finish(self):
-            load = trace_reduce.load
-
-            def keep(path):
-                events = load(path)
-                self.kept = {"devices": events["devices"],
-                             "hero": span_reduce.load_hero(path)}
-                return events
-
-            trace_reduce.load = keep
-            try:
-                return super().finish()
-            finally:
-                trace_reduce.load = load
-
-    spans.disable()
-    spans.clear()
     keep: dict = {}
-    saved = harness.Recorder, harness.Tracer
-    harness.Recorder, harness.Tracer = SpanRecorder, KeepingTracer
-    try:
-        out = harness.run(cell, seed, seconds, trace,
-                          t_start=time.monotonic(), device=device,
-                          pipe_hook=pipe_hook, keep=keep)
-    finally:
-        harness.Recorder, harness.Tracer = saved
-        recorded, counters = spans.drain()
-        spans.disable()
+    out = harness.run(cell, seed, seconds, trace, t_start=time.monotonic(),
+                      device=device, pipe_hook=pipe_hook, keep=keep,
+                      record_spans=record)
     if record:
-        tr = KeepingTracer.last if trace else None
-        kept = tr.kept if tr is not None else {"devices": {}, "hero": []}
+        ctx = keep["ctx"]
         reduced = span_reduce.reduce(
-            recorded, counters, kept["hero"], kept["devices"],
-            keep["window"], (tr.t0, tr.t1) if tr is not None else None,
-            keep["queries"])
+            ctx.program_spans, ctx.counters, ctx.hero, ctx.devices,
+            ctx.window, ctx.trace_window, ctx.queries)
         for line in span_reduce.table(reduced):
             print(line, file=sys.stderr)
-        out["spans"] = dict(reduced, counters=counters,
-                            recorded=len(recorded))
+        out["spans"] = dict(reduced, counters=ctx.counters,
+                            recorded=len(ctx.program_spans))
     return out
 
 

@@ -9,6 +9,7 @@ import pytest
 import flops
 import harness
 import peaks
+import trace_reduce
 
 CONFIG = json.loads((Path(__file__).parent / "configs" / "qwen3-rag.json")
                     .read_text())
@@ -80,3 +81,19 @@ def test_decode_mfu_counts_real_tokens_over_the_union_of_spans():
     work = (flops.decode_flops(CONFIG["models"]["chat"], 16)
             + flops.decode_flops(CONFIG["models"]["search"], 8))
     assert read(ctx) == pytest.approx(100 * work / (2.0 * 197e12))
+
+
+def test_decode_mfu_of_the_dense_configuration_is_the_dense_count():
+    # a configuration whose entries name no reference module reads the
+    # same float as 2 x the dense parameters per token gives
+    read = harness.reader("decode_mfu")
+    spans = [harness.Span("chat_decode", 0.0, 1.3, [0, 1], 2, 13, None),
+             harness.Span("refine_decode", 0.2, 1.9, [3], 1, 7, None),
+             harness.Span("rewrite_decode", 2.5, 3.1, [2], 1, 5, None)]
+    ctx = SimpleNamespace(peaks=V5E, config=CONFIG, spans=spans)
+    roles = CONFIG["stage_roles"]
+    work = sum(flops.decode_flops(CONFIG["models"][roles[s.stage]],
+                                  s.tokens) for s in spans)
+    wall = sum(e - s for s, e in trace_reduce.union(
+        [(s.t0, s.t1) for s in spans]))
+    assert read(ctx) == 100.0 * work / (wall * V5E["bf16_flops"])
