@@ -6,7 +6,8 @@ from typing import Dict, List
 
 from repro.configs.base import (  # noqa: F401 (re-export)
     MLAConfig, MoEConfig, ModelConfig, SSMConfig, EncDecConfig, VLMConfig,
-    ShapeConfig, SHAPES, SHAPES_BY_NAME, reduced, shape_applicable)
+    YarnConfig, ShapeConfig, SHAPES, SHAPES_BY_NAME, reduced,
+    shape_applicable)
 
 _ARCH_MODULES: Dict[str, str] = {
     "deepseek-v3-671b": "repro.configs.deepseek_v3_671b",
@@ -33,9 +34,17 @@ def get_config(arch: str) -> ModelConfig:
 
 
 def get_family(name: str) -> Dict[str, ModelConfig]:
-    """Paper RAG model families: 'qwen3' (Fig. 5) or 'bge' (Fig. 6)."""
+    """RAG model families, role -> config: 'qwen3' (the paper's Fig. 5),
+    'bge' (Fig. 6), or 'deepseek-v2-lite' (Qwen3's embedder and reranker,
+    with one DeepSeek-V2-Lite config object as both search and chat)."""
     if name == "qwen3":
         return importlib.import_module("repro.configs.qwen3_family").FAMILY
     if name == "bge":
         return importlib.import_module("repro.configs.bge_family").FAMILY
-    raise KeyError(f"unknown family {name!r}; known: qwen3, bge")
+    if name == "deepseek-v2-lite":
+        qwen3 = importlib.import_module("repro.configs.qwen3_family").FAMILY
+        lite = importlib.import_module("repro.configs.deepseek_v2_lite")
+        return {"embed": qwen3["embed"], "rerank": qwen3["rerank"],
+                "search": lite.CONFIG, "chat": lite.CONFIG}
+    raise KeyError(f"unknown family {name!r}; known: qwen3, bge, "
+                   f"deepseek-v2-lite")

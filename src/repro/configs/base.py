@@ -24,10 +24,40 @@ class MoEConfig:
     first_k_dense: int = 0            # leading dense layers (DeepSeek)
     dense_d_ff: int = 0               # FFN size of those dense layers
     router_aux_loss: float = 0.001    # load-balance loss coefficient
+    norm_topk_prob: bool = True       # renormalise the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0  # scales the routed experts' sum
+    # expert parallelism: this device holds the routed experts
+    # [first_expert, first_expert + num_experts_held) and computes their
+    # part of the result; the router still spans all num_experts.
+    # 0 holds every expert.
+    num_experts_held: int = 0
+    first_expert: int = 0
 
     @property
     def enabled(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def held(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.num_experts_held or self.num_experts
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (DeepSeek v2): NTK-by-parts rope frequencies and
+    an attention temperature.  ``factor`` 1 is plain rope."""
+
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @property
+    def enabled(self) -> bool:
+        return self.factor > 1.0
 
 
 @dataclass(frozen=True)
@@ -39,6 +69,7 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    yarn: YarnConfig = field(default_factory=YarnConfig)
 
     @property
     def enabled(self) -> bool:
@@ -140,8 +171,9 @@ class ModelConfig:
         return self.encdec.enabled
 
     def param_count(self) -> int:
-        """Analytic parameter count (matches the constructed pytree closely;
-        used for roofline MODEL_FLOPS = 6*N*D and the perf model)."""
+        """Analytic parameter count (the constructed pytree but the final
+        norm's d scales; used for roofline MODEL_FLOPS = 6*N*D and the perf
+        model).  Of a MoE layer it counts the routed experts held here."""
         d, L, V = self.d_model, self.num_layers, self.vocab_size
         n = V * d  # embedding
         if not self.tie_embeddings:
@@ -163,18 +195,23 @@ class ModelConfig:
         return n
 
     def active_param_count(self) -> int:
-        """Active (per-token) parameters — for MoE roofline MODEL_FLOPS."""
+        """Active (per-token) parameters — for MoE roofline MODEL_FLOPS.
+        With a share of the experts held (``moe.num_experts_held``) a
+        token's routed experts lie on this device top_k · held / E times
+        on average, and only those count."""
         if not self.moe.enabled:
             return self.param_count()
+        m = self.moe
         d, L, V = self.d_model, self.num_layers, self.vocab_size
         n = V * d * (1 if self.tie_embeddings else 2)
+        routed = m.top_k * m.held / m.num_experts
         for layer in range(L):
             n += self._attn_params(layer) + 2 * d
-            if layer < self.moe.first_k_dense:
-                n += 3 * d * self.moe.dense_d_ff
+            if layer < m.first_k_dense:
+                n += 3 * d * m.dense_d_ff
             else:
-                active = self.moe.top_k + self.moe.num_shared_experts
-                n += 3 * d * self.moe.d_ff * active + d * self.moe.num_experts  # + router
+                n += round(3 * d * m.d_ff * (routed + m.num_shared_experts)) \
+                    + d * m.num_experts                     # + router
         return n
 
     def _dense_attn_params(self) -> int:
@@ -210,8 +247,12 @@ class ModelConfig:
         if self.mla.enabled:
             m = self.mla
             nh = self.num_heads
-            p = d * m.q_lora_rank + m.q_lora_rank * nh * m.qk_head_dim       # q path
-            p += d * (m.kv_lora_rank + m.qk_rope_head_dim)                   # kv down
+            if m.q_lora_rank:                                                # q path
+                p = (d * m.q_lora_rank + m.q_lora_rank
+                     + m.q_lora_rank * nh * m.qk_head_dim)
+            else:
+                p = d * nh * m.qk_head_dim
+            p += d * (m.kv_lora_rank + m.qk_rope_head_dim) + m.kv_lora_rank  # kv down
             p += m.kv_lora_rank * nh * (m.qk_nope_head_dim + m.v_head_dim)   # kv up
             p += nh * m.v_head_dim * d                                       # o proj
             return p
@@ -229,7 +270,7 @@ class ModelConfig:
         if self.moe.enabled:
             if layer < self.moe.first_k_dense:
                 return 3 * d * self.moe.dense_d_ff
-            total = self.moe.num_experts + self.moe.num_shared_experts
+            total = self.moe.held + self.moe.num_shared_experts
             return 3 * d * self.moe.d_ff * total + d * self.moe.num_experts
         return self._mlp_params(self.d_ff)
 
@@ -278,14 +319,20 @@ def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 64,
         vocab_size=vocab, max_seq_len=4096, dtype="float32", remat="none",
     )
     if cfg.moe.enabled:
+        m = cfg.moe
+        # a held share keeps its fraction of the experts, at least one
+        held = max(1, 4 * m.num_experts_held // m.num_experts) \
+            if m.num_experts_held else 0
         changes["moe"] = dataclasses.replace(
-            cfg.moe, num_experts=4, top_k=2,
-            num_shared_experts=min(cfg.moe.num_shared_experts, 1),
-            d_ff=64, first_k_dense=min(cfg.moe.first_k_dense, 1), dense_d_ff=128)
-        changes["d_ff"] = 0
+            m, num_experts=4, top_k=2,
+            num_shared_experts=min(m.num_shared_experts, 1),
+            d_ff=64, first_k_dense=min(m.first_k_dense, 1), dense_d_ff=128,
+            num_experts_held=held,
+            first_expert=min(m.first_expert * 4 // m.num_experts, 4 - held))
     if cfg.mla.enabled:
         changes["mla"] = dataclasses.replace(
-            cfg.mla, kv_lora_rank=32, q_lora_rank=32,
+            cfg.mla, kv_lora_rank=32,
+            q_lora_rank=32 if cfg.mla.q_lora_rank else 0,
             qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
         changes["head_dim"] = 16
     if cfg.ssm.enabled:

@@ -105,9 +105,19 @@ CT_LM_REAL_TOKENS = "lm.real_tokens"      # tokens returned to real rows
 CT_LM_PAD_ROWS = "lm.pad_rows"            # padding rows per call
 CT_LM_KV_BYTES_RESERVED = "lm.kv_bytes_reserved"  # the call's cache
 CT_LM_KV_BYTES_USED = "lm.kv_bytes_used"  # positions the call wrote
+# what a MoE generator's routers did in the decode steps, real rows only
+CT_MOE_ROUTED = "moe.routed"              # assignments over all experts
+CT_MOE_ROUTED_HELD = "moe.routed_held"    # of those, to held experts
+CT_MOE_EXPERTS_HIT = "moe.experts_hit"    # held experts with an
+#                                           assignment, per layer-step
+CT_MOE_LAYER_STEPS = "moe.layer_steps"    # MoE layers x decode steps
+CT_MOE_DROPPED = "moe.dropped"            # held assignments not computed
+#                                           (0: the layer is dropless)
 
 ALL_COUNTERS = frozenset({
     CT_RUNTIME_PASSES, CT_EXECUTOR_CANCELLED_RUNS, CT_LM_STEPS,
     CT_LM_FETCHES, CT_LM_REAL_TOKENS, CT_LM_PAD_ROWS,
-    CT_LM_KV_BYTES_RESERVED, CT_LM_KV_BYTES_USED,
+    CT_LM_KV_BYTES_RESERVED, CT_LM_KV_BYTES_USED, CT_MOE_ROUTED,
+    CT_MOE_ROUTED_HELD, CT_MOE_EXPERTS_HIT, CT_MOE_LAYER_STEPS,
+    CT_MOE_DROPPED,
 })

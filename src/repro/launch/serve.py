@@ -4,10 +4,11 @@
 
 Runs the full executable pipeline — chunker, embedder, vector DB, reranker,
 rewriter/planner agents, chat generation — on heterogeneous PU-group
-executors under the HeRo scheduler.  The stage models are the Qwen3 family
-(``configs/qwen3_family.py``) at their published widths in bf16, with
-seeded random weights; ``--reduced`` builds tiny float32 configs for CPU
-runs.  Every PU executor shares the default device.
+executors under the HeRo scheduler.  The stage models are a family of
+``configs.get_family`` (by default Qwen3, ``configs/qwen3_family.py``) at
+their published widths in bf16, with seeded random weights; ``--reduced``
+builds tiny float32 configs for CPU runs.  Every PU executor shares the
+default device.
 """
 from __future__ import annotations
 
@@ -53,30 +54,36 @@ class Pipeline:
     db: VectorDB
 
 
-def build_pipeline(seed: int = 0, *, reduced_widths: bool = False,
+def build_pipeline(seed: int = 0, *, family: str = "qwen3",
+                   reduced_widths: bool = False,
                    spec_decode: bool = False) -> Pipeline:
-    """Build the Qwen3 stage family (plus the draft model when
+    """Build the stage models of ``family`` (plus the draft model when
     ``spec_decode``) with weights drawn from ``seed``: role i of the family
-    is seeded with ``fold_in(seed, i)``."""
-    cfgs = dict(get_family("qwen3"))
+    is seeded with ``fold_in(seed, i)``.  Roles that share one config
+    object share one model, built once from the first such role's seed:
+    ``models[a] is models[b]``."""
+    cfgs = dict(get_family(family))
     if spec_decode:
         cfgs["draft"] = DRAFT_MODELS[DEFAULT_DRAFT_MODEL]
-    if reduced_widths:
-        cfgs = {role: reduced(cfg) for role, cfg in cfgs.items()}
     key = jax.random.PRNGKey(seed)
-    models = {role: (cfg, jax.jit(build_model(cfg).init)(
-                  jax.random.fold_in(key, i)))
-              for i, (role, cfg) in enumerate(cfgs.items())}
+    built: Dict[int, Tuple[ModelConfig, dict]] = {}
+    models = {}
+    for i, (role, cfg) in enumerate(cfgs.items()):
+        if id(cfg) not in built:
+            small = reduced(cfg) if reduced_widths else cfg
+            built[id(cfg)] = (small, jax.jit(build_model(small).init)(
+                jax.random.fold_in(key, i)))
+        models[role] = built[id(cfg)]
     return Pipeline(
         models=models,
-        tok=HashTokenizer(cfgs["embed"].vocab_size),
+        tok=HashTokenizer(models["embed"][0].vocab_size),
         embedder=Embedder(*models["embed"]),
         reranker=Reranker(*models["rerank"]),
-        rewriter=LMAgent(*models["search"], max_len=256),
-        chat=LMAgent(*models["chat"], max_len=512),
-        draft=(LMAgent(*models["draft"], max_len=256)
+        rewriter=LMAgent(*models["search"], max_len=256, role="search"),
+        chat=LMAgent(*models["chat"], max_len=512, role="chat"),
+        draft=(LMAgent(*models["draft"], max_len=256, role="draft")
                if spec_decode else None),
-        db=VectorDB(dim=cfgs["embed"].d_model))
+        db=VectorDB(dim=models["embed"][0].d_model))
 
 
 def stage_fns(pipe: Pipeline):
@@ -89,9 +96,20 @@ def stage_fns(pipe: Pipeline):
 
     docs = synth_documents(4, 400, seed=7)
     chunks = chunk_documents(docs, tok)
-    q_ids = tok.encode(synth_query(seed=3))
-    prompt = q_ids[:PROMPT_TOKENS]
+    query = synth_query(seed=3)
+    q_ids = tok.encode(query)
     q_vec = embedder.embed([q_ids])
+
+    def prompt_of(agent):
+        """The query's first tokens in the agent's own vocabulary (the
+        retrieval tokenizer's ids where they fit it)."""
+        vocab = agent.cfg.vocab_size
+        ids = q_ids if vocab >= tok.vocab_size \
+            else HashTokenizer(vocab).encode(query)
+        return ids[:PROMPT_TOKENS]
+
+    prompts = {id(a): prompt_of(a) for a in (rewriter, chat, draft)
+               if a is not None}
 
     def fn_embed(node, batch):
         ids = [m.id for m in node.payload.get("members", ())] or [node.id]
@@ -125,9 +143,10 @@ def stage_fns(pipe: Pipeline):
             # (workload = passes × width); candidates are greedy, so the
             # verify fn reproduces them for its acceptance comparison
             return need_draft().generate(
-                prompt, max_new=min(node.workload, 16)).token_ids
+                prompts[id(draft)], max_new=min(node.workload, 16)).token_ids
         agent = rewriter if node.stage.startswith(("rewrite", "plan")) \
             else chat
+        prompt = prompts[id(agent)]
         if node.kind == "stream_prefill":
             return "prefill"
         members = node.payload.get("members")
@@ -145,7 +164,7 @@ def stage_fns(pipe: Pipeline):
                 # here is exact) and stamp the per-member accept counts
                 # the round boundary folds into the accept-rate EWMA
                 douts = need_draft().generate_batch(
-                    [prompt] * len(members), max_new=group)
+                    [prompts[id(draft)]] * len(members), max_new=group)
                 node.payload["spec_accepts"] = {
                     m.id: sum(1 for a, b in zip(g.token_ids, dg.token_ids)
                               if a == b)
@@ -157,7 +176,7 @@ def stage_fns(pipe: Pipeline):
     reranker.score(q_ids, [chunks[0].token_ids])
     for agent in (rewriter, chat, draft):
         if agent is not None:
-            agent.generate(prompt, max_new=2, stop_at_eos=False)
+            agent.generate(prompts[id(agent)], max_new=2, stop_at_eos=False)
 
     fns = {s: fn_llm for s in
            ("rewrite_prefill", "rewrite_decode", "plan_prefill",
@@ -169,10 +188,11 @@ def stage_fns(pipe: Pipeline):
     return fns
 
 
-def build_stage_fns(seed: int = 0, *, reduced_widths: bool = False,
-                    spec_decode: bool = False):
+def build_stage_fns(seed: int = 0, *, family: str = "qwen3",
+                    reduced_widths: bool = False, spec_decode: bool = False):
     """``stage_fns(build_pipeline(...))`` — published widths by default."""
-    return stage_fns(build_pipeline(seed, reduced_widths=reduced_widths,
+    return stage_fns(build_pipeline(seed, family=family,
+                                    reduced_widths=reduced_widths,
                                     spec_decode=spec_decode))
 
 
@@ -198,6 +218,8 @@ def main():
                          "coupled (draft, verify) pairs — the real draft "
                          "model streams candidates the target verifies in "
                          "one sweep (implies --serve admission)")
+    ap.add_argument("--family", default="qwen3",
+                    help="the stage-model family (configs.get_family)")
     ap.add_argument("--reduced", action="store_true",
                     help="tiny float32 stage models (CPU runs) instead of "
                          "the published widths")
@@ -210,7 +232,7 @@ def main():
         traces = shared_corpus_traces(args.dataset, args.queries, seed=1)
     else:
         traces = sample_traces(args.dataset, args.queries, seed=1)
-    sess = HeroSession(world="sd8gen4", family="qwen3", backend="live",
+    sess = HeroSession(world="sd8gen4", family=args.family, backend="live",
                        means=default_means(traces),
                        options=SessionOptions(
                            coalesce=bool(args.serve),
@@ -218,7 +240,7 @@ def main():
                            spec_decode=bool(args.spec_decode),
                            cfg_overrides=SERVE_OVERRIDES),
                        stage_fns=build_stage_fns(
-                           reduced_widths=args.reduced,
+                           family=args.family, reduced_widths=args.reduced,
                            spec_decode=args.spec_decode))
     for qi, tr in enumerate(traces):
         sess.submit(tr, wf=args.workflow,

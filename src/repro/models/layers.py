@@ -62,10 +62,13 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
                             / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               inv_freq: Optional[jax.Array] = None) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: (..., seq).
+    ``inv_freq`` (head_dim//2,) replaces the plain ``theta`` frequencies
+    (YaRN, ``models/mla.py``)."""
     head_dim = x.shape[-1]
-    inv = rope_freqs(head_dim, theta)                       # (hd/2,)
+    inv = rope_freqs(head_dim, theta) if inv_freq is None else inv_freq
     ang = positions[..., None].astype(jnp.float32) * inv    # (..., s, hd/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     cos = cos[..., None, :]                                  # (..., s, 1, hd/2)

@@ -30,6 +30,10 @@ Params = Dict[str, Any]
 
 NEG_POS = -(1 << 30)  # ring-buffer "empty slot" position
 
+# the cache's ``moe_stats`` entries: moe.ROUTE_STATS summed over the MoE
+# layers of every decode step, then the MoE layer-steps counted
+MOE_STATS = MOE.ROUTE_STATS + ("layer_steps",)
+
 
 def _stack_init(fn, key, n: int):
     return jax.vmap(fn)(jax.random.split(key, n))
@@ -146,20 +150,24 @@ def init_moe_block(key, cfg: ModelConfig, *, dense_ffn: bool) -> Params:
 
 
 def moe_block(p: Params, cfg: ModelConfig, x, *, positions, cache=None,
-              cache_idx=None, capacity_factor=1.25):
+              cache_idx=None, token_mask=None):
+    """Returns (x, aux, new_cache, route_stats); the stats (the MoE
+    layer's ``moe.ROUTE_STATS`` over ``token_mask``) are None for a dense
+    layer or without a mask."""
     h, new_cache = MLA.mla_attention(
         p["mla"], L.rmsnorm(p["ln1"], x, cfg.norm_eps), cfg.mla,
         positions=positions, theta=cfg.rope_theta, cache=cache,
         cache_idx=cache_idx)
     x = x + h
     aux = jnp.zeros((), jnp.float32)
+    stats = None
     h2 = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
     if "moe" in p:
-        y, aux = MOE.moe_ffn(p["moe"], h2, cfg.moe,
-                             capacity_factor=capacity_factor)
+        y, aux, stats = MOE.moe_ffn(p["moe"], h2, cfg.moe,
+                                    token_mask=token_mask)
     else:
         y = L.mlp(p["mlp"], h2)
-    return constrain(x + y, ("batch", None, None)), aux, new_cache
+    return constrain(x + y, ("batch", None, None)), aux, new_cache, stats
 
 
 # ---------------------------------------------------------------------------
@@ -280,6 +288,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
                              dtype),
             "krope": jnp.zeros(
                 (cfg.num_layers, batch, max_len, m.qk_rope_head_dim), dtype)}
+        # what the MoE layers' routers did over the decode steps, summed
+        # over layers and steps (MOE_STATS), counting the rows set in
+        # ``rows`` (all of them unless the caller says otherwise)
+        cache["moe_stats"] = jnp.zeros((len(MOE_STATS),), jnp.int32)
+        cache["rows"] = jnp.ones((batch,), bool)
     elif fam == "hybrid":
         W = _window_for(cfg, max_len)
         n_attn = cfg.num_layers // cfg.ssm.attn_every
@@ -443,31 +456,40 @@ def _run_vlm(params, cfg, batch, x, positions, cache, cache_idx, mode,
 
 
 def _run_moe(params, cfg, x, positions, cache, cache_idx, mode, new_cache):
-    T = x.shape[0] * x.shape[1]
-    cap = 2.0 if T < 4096 else 1.25
     nk = cfg.moe.first_k_dense
     aux_total = jnp.zeros((), jnp.float32)
+    # a decode step counts what its MoE layers' routers did
+    mask = None
+    if mode == "decode" and cache is not None and "moe_stats" in cache:
+        mask = jnp.broadcast_to(cache["rows"][:, None], x.shape[:2])
+    n_route = len(MOE.ROUTE_STATS)
 
     def mk_body(dense_ffn):
         def body(carry, xs):
-            h, aux = carry
+            h, aux, st = carry
             p, c = xs
-            h, a, nc = moe_block(p, cfg, h, positions=positions, cache=c,
-                                 cache_idx=cache_idx, capacity_factor=cap)
-            return (h, aux + a), nc
+            h, a, nc, s = moe_block(p, cfg, h, positions=positions, cache=c,
+                                    cache_idx=cache_idx,
+                                    token_mask=None if dense_ffn else mask)
+            return (h, aux + a, st if s is None else st + s), nc
         return _remat(body, cfg, mode)
 
     lc = None if cache is None else cache["layers"]
     lc_d = None if lc is None else jax.tree.map(lambda a: a[:nk], lc)
     lc_m = None if lc is None else jax.tree.map(lambda a: a[nk:], lc)
 
-    (x, aux_total), ncd = jax.lax.scan(
-        mk_body(True), (x, aux_total), (params["dense_blocks"], lc_d))
-    (x, aux_total), ncm = jax.lax.scan(
-        mk_body(False), (x, aux_total), (params["moe_blocks"], lc_m))
+    st = jnp.zeros((n_route,), jnp.int32)
+    (x, aux_total, st), ncd = jax.lax.scan(
+        mk_body(True), (x, aux_total, st), (params["dense_blocks"], lc_d))
+    (x, aux_total, st), ncm = jax.lax.scan(
+        mk_body(False), (x, aux_total, st), (params["moe_blocks"], lc_m))
     if new_cache is not None:
         new_cache["layers"] = jax.tree.map(
             lambda a, b2: jnp.concatenate([a, b2], axis=0), ncd, ncm)
+        if mask is not None:
+            n_moe = cfg.num_layers - nk
+            new_cache["moe_stats"] = cache["moe_stats"] + jnp.concatenate(
+                [st, jnp.array([n_moe], jnp.int32)])
     return x, aux_total, new_cache
 
 
@@ -641,7 +663,7 @@ def loss_fn(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array]
         h = jnp.einsum("bsd,dk->bsk", jnp.concatenate([h0, h], -1),
                        mtp["proj"])
         pos = jnp.arange(h.shape[1])
-        h, _, _ = moe_block(mtp["block"], cfg, h, positions=pos)
+        h, _, _, _ = moe_block(mtp["block"], cfg, h, positions=pos)
         mtp_logits = _logits(params, h)
         mtp_ce = cross_entropy(mtp_logits[:, :-1], labels[:, 2:])
         loss = loss + 0.3 * mtp_ce

@@ -4,27 +4,86 @@ Train/prefill use the *naive* form (materialize per-head K/V from the latent)
 which is compute-optimal; decode uses the *absorbed* form (scores computed
 directly against the cached latent) which is memory-optimal — exactly the KV
 reduction MLA was designed for.  Cache = {ckv: (b, S, r), krope: (b, S, e_r)}.
+
+q is projected through a low-rank latent (``q_lora_rank`` > 0: wq_a, q_norm,
+wq_b) or directly (``q_lora_rank`` 0: wq, DeepSeek-V2-Lite).  With YaRN
+(``MLAConfig.yarn``) the rope part uses NTK-by-parts frequencies and the
+softmax scale gains the factor mscale².
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.configs.base import MLAConfig
+from repro.configs.base import MLAConfig, YarnConfig
 from repro.models.layers import apply_rope, dense_init, init_rmsnorm, rmsnorm
 from repro.models.sharding import constrain
 
 Params = Dict[str, Any]
 
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_inv_freq(m: MLAConfig, theta: float) -> Optional[np.ndarray]:
+    """(qk_rope_head_dim // 2,) YaRN frequencies, or None without YaRN:
+    pairs below the fast correction bound keep theta's frequency, pairs
+    from the slow bound up are divided by ``factor``, a linear ramp
+    between."""
+    y: YarnConfig = m.yarn
+    if not y.enabled:
+        return None
+    dim = m.qk_rope_head_dim
+
+    def bound(rotations):
+        return dim * math.log(y.original_max_position_embeddings
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(bound(y.beta_fast)), 0)
+    hi = min(math.ceil(bound(y.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - lo) / max(hi - lo, 1e-3), 0, 1)
+    extra = 1.0 / theta ** (np.arange(0, dim, 2) / dim)
+    return (extra / y.factor * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def softmax_scale(m: MLAConfig) -> float:
+    """qk_head_dim ** -0.5, times mscale² under YaRN."""
+    y = m.yarn
+    ms = _yarn_mscale(y.factor, y.mscale_all_dim) if y.mscale_all_dim else 1.0
+    return m.qk_head_dim ** -0.5 * ms * ms
+
+
+def _rope_gain(m: MLAConfig) -> float:
+    """YaRN's factor on the rotated rope part (1 where mscale ==
+    mscale_all_dim, as in DeepSeek-V2)."""
+    y = m.yarn
+    return _yarn_mscale(y.factor, y.mscale) / _yarn_mscale(y.factor,
+                                                           y.mscale_all_dim)
+
+
+def _rope(x, positions, theta, m: MLAConfig):
+    out = apply_rope(x, positions, theta, rope_inv_freq(m, theta))
+    gain = _rope_gain(m)
+    return out if gain == 1.0 else (out * gain).astype(out.dtype)
+
+
 def init_mla(key, d: int, n_heads: int, m: MLAConfig, dtype) -> Params:
     ks = jax.random.split(key, 7)
+    if m.q_lora_rank:
+        q = {"wq_a": dense_init(ks[0], (d, m.q_lora_rank), dtype),
+             "q_norm": init_rmsnorm(m.q_lora_rank, dtype),
+             "wq_b": dense_init(ks[1], (m.q_lora_rank, n_heads,
+                                        m.qk_head_dim), dtype)}
+    else:
+        q = {"wq": dense_init(ks[0], (d, n_heads, m.qk_head_dim), dtype)}
     return {
-        "wq_a": dense_init(ks[0], (d, m.q_lora_rank), dtype),
-        "q_norm": init_rmsnorm(m.q_lora_rank, dtype),
-        "wq_b": dense_init(ks[1], (m.q_lora_rank, n_heads, m.qk_head_dim), dtype),
+        **q,
         "wkv_a": dense_init(ks[2], (d, m.kv_lora_rank + m.qk_rope_head_dim), dtype),
         "kv_norm": init_rmsnorm(m.kv_lora_rank, dtype),
         "wk_b": dense_init(ks[3], (m.kv_lora_rank, n_heads, m.qk_nope_head_dim), dtype),
@@ -36,11 +95,14 @@ def init_mla(key, d: int, n_heads: int, m: MLAConfig, dtype) -> Params:
 
 def _project_q(p: Params, x: jax.Array, m: MLAConfig, positions, theta):
     """-> q_nope (b,s,h,e_n), q_rope (b,s,h,e_r)."""
-    ql = rmsnorm(p["q_norm"], jnp.einsum("bsd,dr->bsr", x, p["wq_a"]))
-    q = constrain(jnp.einsum("bsr,rhe->bshe", ql, p["wq_b"]),
-                  ("batch", None, "model", None))
+    if "wq" in p:
+        q = jnp.einsum("bsd,dhe->bshe", x, p["wq"])
+    else:
+        ql = rmsnorm(p["q_norm"], jnp.einsum("bsd,dr->bsr", x, p["wq_a"]))
+        q = jnp.einsum("bsr,rhe->bshe", ql, p["wq_b"])
+    q = constrain(q, ("batch", None, "model", None))
     q_nope = q[..., : m.qk_nope_head_dim]
-    q_rope = apply_rope(q[..., m.qk_nope_head_dim:], positions, theta)
+    q_rope = _rope(q[..., m.qk_nope_head_dim:], positions, theta, m)
     return q_nope, q_rope
 
 
@@ -48,7 +110,7 @@ def _project_kv_latent(p: Params, x: jax.Array, m: MLAConfig, positions, theta):
     """-> ckv (b,s,r), k_rope (b,s,e_r) — what gets cached."""
     kv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
     ckv = rmsnorm(p["kv_norm"], kv[..., : m.kv_lora_rank])
-    k_rope = apply_rope(kv[..., None, m.kv_lora_rank:], positions, theta)
+    k_rope = _rope(kv[..., None, m.kv_lora_rank:], positions, theta, m)
     return ckv, k_rope[..., 0, :]
 
 
@@ -59,7 +121,7 @@ def mla_attention(p: Params, x: jax.Array, m: MLAConfig, *,
                   ) -> Tuple[jax.Array, Optional[Params]]:
     dtype = x.dtype
     b, s, d = x.shape
-    scale = 1.0 / jnp.sqrt(jnp.float32(m.qk_head_dim))
+    scale = jnp.float32(softmax_scale(m))
     q_nope, q_rope = _project_q(p, x, m, positions, theta)
     ckv, k_rope = _project_kv_latent(p, x, m, positions, theta)
 

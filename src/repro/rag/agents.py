@@ -17,9 +17,12 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.events import (CT_LM_FETCHES, CT_LM_KV_BYTES_RESERVED,
                                CT_LM_KV_BYTES_USED, CT_LM_PAD_ROWS,
-                               CT_LM_REAL_TOKENS, CT_LM_STEPS, SP_LM_CALL,
+                               CT_LM_REAL_TOKENS, CT_LM_STEPS, CT_MOE_DROPPED,
+                               CT_MOE_EXPERTS_HIT, CT_MOE_LAYER_STEPS,
+                               CT_MOE_ROUTED, CT_MOE_ROUTED_HELD, SP_LM_CALL,
                                SP_LM_FETCH, SP_LM_STEP)
 from repro.models import Model, build_model
+from repro.models.lm import MOE_STATS
 from repro.rag.embedder import CALL_WIDTH
 from repro.rag.tokenizer import EOS
 from repro.serving import spans
@@ -47,22 +50,32 @@ class LMAgent:
     The steps are separate programs, not one on-device loop: a program
     that runs the layer stack more than once (a prefill and a decode
     loop) makes the TPU compiler copy the q/k/v weight stacks into
-    another layout on every call, 1.13 GB for the 4B chat model."""
+    another layout on every call, 1.13 GB for the 4B chat model.
 
-    def __init__(self, cfg: ModelConfig, params, max_len: int = 512):
+    A MoE model's decode steps also count, on the device, what their
+    routers did for the call's real rows (``lm.MOE_STATS``); the counts
+    come back in the call's one fetch.  ``role`` names the pipeline role
+    the agent serves in its ``lm.call`` spans."""
+
+    def __init__(self, cfg: ModelConfig, params, max_len: int = 512,
+                 role: str = ""):
         self.cfg = cfg
         self.params = params
         self.max_len = max_len
+        self.role = role or cfg.name
         self.model: Model = build_model(cfg)
         model = self.model
-        # bytes of one row's keys and values at one position, summed over
-        # the layers (a dense attention cache)
-        self._kv_slot_bytes = (cfg.num_layers * 2 * cfg.num_kv_heads
-                               * cfg.resolved_head_dim
-                               * jnp.dtype(cfg.dtype).itemsize)
+        # bytes of one row's cache at one position, summed over the
+        # layers, as the model builds it (keys and values, or MLA's latent)
+        layers = jax.eval_shape(lambda: model.init_cache(1, 1))["layers"]
+        self._kv_slot_bytes = sum(a.size * a.dtype.itemsize
+                                  for a in jax.tree.leaves(layers))
+        self._moe_stats = cfg.moe.enabled
 
-        def prefill(params, tokens):
+        def prefill(params, tokens, n_real=None):
             cache = model.init_cache(tokens.shape[0], max_len)
+            if n_real is not None:
+                cache["rows"] = jnp.arange(tokens.shape[0]) < n_real
             logits, cache = model.prefill(params, {"tokens": tokens}, cache)
             return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), cache
 
@@ -81,17 +94,21 @@ class LMAgent:
         (the steps after it run on the device and are dropped)."""
         n = len(prompts)
         rows = list(prompts) + [prompts[0]] * (CALL_WIDTH - n)
-        with spans.span(SP_LM_CALL, role=self.cfg.name, width=CALL_WIDTH,
-                        rows=n):
+        tokens = jnp.asarray(rows, jnp.int32)
+        with spans.span(SP_LM_CALL, role=self.role, width=CALL_WIDTH,
+                        rows=n, prompt=len(rows[0]), steps=max_new - 1):
             with spans.span(SP_LM_STEP):
-                tok, cache = self._prefill(self.params,
-                                           jnp.asarray(rows, jnp.int32))
+                tok, cache = (self._prefill(self.params, tokens, n)
+                              if self._moe_stats
+                              else self._prefill(self.params, tokens))
                 toks = [tok]
                 for _ in range(max_new - 1):
                     tok, cache = self._decode(self.params, tok, cache)
                     toks.append(tok)
+                moe = cache["moe_stats"] if self._moe_stats else None
                 with spans.span(SP_LM_FETCH):
-                    host = np.stack(jax.device_get(toks), axis=1)[:n]
+                    got, moe = jax.device_get((toks, moe))
+                    host = np.stack(got, axis=1)[:n]
         if stop_at_eos:
             eos = np.flatnonzero(host[0] == EOS)
             if eos.size:
@@ -107,6 +124,14 @@ class LMAgent:
             # the prefill writes the prompt's positions, each step one more
             spans.count(CT_LM_KV_BYTES_USED,
                         self._kv_slot_bytes * n * (len(rows[0]) + steps))
+            if moe is not None:
+                got = dict(zip(MOE_STATS, moe.tolist()))
+                spans.count(CT_MOE_ROUTED, got["routed"])
+                spans.count(CT_MOE_ROUTED_HELD, got["routed_held"])
+                spans.count(CT_MOE_EXPERTS_HIT, got["experts_hit"])
+                spans.count(CT_MOE_LAYER_STEPS, got["layer_steps"])
+                spans.count(CT_MOE_DROPPED,
+                            got["routed_held"] - got["computed"])
         return host.tolist()
 
     def generate(self, prompt_ids: Sequence[int], max_new: int = 32,

@@ -18,9 +18,14 @@ DRAFT_MODELS: Dict[str, ModelConfig] = {
 
 
 def _kv_bytes_token(cfg: ModelConfig, bytes_per_param: float = 1.0) -> float:
-    """K+V cache bytes per context token (GQA): 2 · layers · kv_heads ·
-    head_dim · bytes — what KV-residency tracking and the migration-cost
-    model charge per resident token."""
+    """Cache bytes per context token — what KV-residency tracking and the
+    migration-cost model charge per resident token: layers ·
+    (kv_lora_rank + qk_rope_head_dim) · bytes for MLA's latent cache,
+    else K+V (GQA), 2 · layers · kv_heads · head_dim · bytes."""
+    if cfg.mla.enabled:
+        return (cfg.num_layers * (cfg.mla.kv_lora_rank
+                                  + cfg.mla.qk_rope_head_dim)
+                * bytes_per_param)
     return (2.0 * cfg.num_layers * cfg.num_kv_heads * cfg.resolved_head_dim
             * bytes_per_param)
 
@@ -29,40 +34,44 @@ def kv_page_bytes(cfg: ModelConfig, page_tokens: int = 64,
                   bytes_per_param: float = 1.0) -> float:
     """Bytes of one paged-KV page for this model's cache shape — the unit
     the tiered page store allocates, demotes and fetches in
-    (``SchedulerConfig.kv_page_tokens`` × the GQA bytes/token above)."""
+    (``SchedulerConfig.kv_page_tokens`` × the bytes/token above)."""
     return page_tokens * _kv_bytes_token(cfg, bytes_per_param)
 
 
 def build_stages(family: Dict[str, ModelConfig],
                  draft_model: Optional[str] = DEFAULT_DRAFT_MODEL
                  ) -> Dict[str, StageModel]:
+    """The generators are priced by the parameters a token uses
+    (``active_param_count``: of a MoE model the routed experts a token
+    reaches on this device, not every expert; a dense model's total)."""
     e, r = family["embed"], family["rerank"]
     s, c = family["search"], family["chat"]
     kv_s, kv_c = _kv_bytes_token(s), _kv_bytes_token(c)
+    n_s, n_c = s.active_param_count(), c.active_param_count()
     stages = {
         "embed": StageModel("embed", e.param_count(), e.d_model,
                             "batchable", item_tokens=128),
         "rerank": StageModel("rerank", r.param_count(), r.d_model,
                              "batchable", item_tokens=160),
         "vsearch": StageModel("vsearch", 0, e.d_model, "search"),
-        "rewrite_prefill": StageModel("rewrite_prefill", s.param_count(),
+        "rewrite_prefill": StageModel("rewrite_prefill", n_s,
                                       s.d_model, "stream_prefill"),
-        "rewrite_decode": StageModel("rewrite_decode", s.param_count(),
+        "rewrite_decode": StageModel("rewrite_decode", n_s,
                                      s.d_model, "stream_decode",
                                      kv_bytes_token=kv_s),
-        "plan_prefill": StageModel("plan_prefill", s.param_count(),
+        "plan_prefill": StageModel("plan_prefill", n_s,
                                    s.d_model, "stream_prefill"),
-        "plan_decode": StageModel("plan_decode", s.param_count(),
+        "plan_decode": StageModel("plan_decode", n_s,
                                   s.d_model, "stream_decode",
                                   kv_bytes_token=kv_s),
-        "refine_prefill": StageModel("refine_prefill", c.param_count(),
+        "refine_prefill": StageModel("refine_prefill", n_c,
                                      c.d_model, "stream_prefill"),
-        "refine_decode": StageModel("refine_decode", c.param_count(),
+        "refine_decode": StageModel("refine_decode", n_c,
                                     c.d_model, "stream_decode",
                                     kv_bytes_token=kv_c),
-        "chat_prefill": StageModel("chat_prefill", c.param_count(),
+        "chat_prefill": StageModel("chat_prefill", n_c,
                                    c.d_model, "stream_prefill"),
-        "chat_decode": StageModel("chat_decode", c.param_count(),
+        "chat_decode": StageModel("chat_decode", n_c,
                                   c.d_model, "stream_decode",
                                   kv_bytes_token=kv_c),
         "web": StageModel("web", 0, 0, "io"),
