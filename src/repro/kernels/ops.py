@@ -19,6 +19,7 @@ from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.int8_matmul import int8_matmul as _int8
 from repro.kernels.int8_matmul import quantize_int8  # noqa: F401 (re-export)
 from repro.kernels.mamba2_scan import ssd_chunk as _ssd
+from repro.kernels.moe_decode import moe_decode as _moe_decode
 from repro.kernels.topk_retrieval import topk_retrieval as _topk
 
 
@@ -79,3 +80,20 @@ def ssd_chunk(x, dt, B, C, dA, *, use_pallas: Optional[bool] = None):
     if run:
         return _ssd(x, dt, B, C, dA, interpret=interp)
     return ref.ssd_chunk_ref(x, dt, B, C, dA)
+
+
+@functools.partial(jax.jit, static_argnames=("use_pallas",))
+def moe_decode(x, gates, w_gate, w_up, w_down, layer, *,
+               use_pallas: Optional[bool] = None):
+    """A MoE layer's held experts over a decode step's tokens, from the
+    whole stacks of every MoE layer (``[L, held, ...]``) and this layer's
+    index.  Returns (y (T, d) float32, read (held,) bool): the kernel
+    reads only the experts some row routes to; the reference reads every
+    held expert."""
+    run, interp = _mode(use_pallas)
+    if run:
+        return _moe_decode(x, gates, w_gate, w_up, w_down, layer,
+                           interpret=interp)
+    y = ref.moe_decode_ref(x, gates, w_gate[layer], w_up[layer],
+                           w_down[layer])
+    return y, jnp.ones((w_gate.shape[1],), bool)

@@ -38,6 +38,17 @@ def topk_retrieval_ref(queries, corpus, k, n_valid=None):
     return vals, idxs.astype(jnp.int32)
 
 
+def moe_decode_ref(x, gates, w_gate, w_up, w_down):
+    """Every held expert on every token, each output weighted by the
+    row's gate (0 where it did not route there).  x (T, d); gates
+    (T, held) float32; w_gate, w_up (held, d, ff); w_down (held, ff, d).
+    Returns (T, d) float32."""
+    gate = jnp.einsum("td,hdf->htf", x, w_gate)
+    up = jnp.einsum("td,hdf->htf", x, w_up)
+    out = jnp.einsum("htf,hfd->htd", jax.nn.silu(gate) * up, w_down)
+    return jnp.einsum("htd,th->td", out.astype(jnp.float32), gates)
+
+
 def ssd_chunk_ref(x, dt, B, C, dA):
     """Intra-chunk SSD oracle.  Shapes as kernels.mamba2_scan.ssd_chunk."""
     xf = x.astype(jnp.float32)

@@ -463,11 +463,23 @@ def _run_moe(params, cfg, x, positions, cache, cache_idx, mode, new_cache):
     if mode == "decode" and cache is not None and "moe_stats" in cache:
         mask = jnp.broadcast_to(cache["rows"][:, None], x.shape[:2])
     n_route = len(MOE.ROUTE_STATS)
+    # a decode step (one new position a row) reads only the held experts
+    # its rows route to: each MoE layer gets the expert stacks whole and
+    # its index (moe.moe_ffn), so the scan never slices (and so copies)
+    # them
+    moe_blocks, experts = params["moe_blocks"], None
+    if mode == "decode" and x.shape[1] == 1:
+        moe_p = dict(moe_blocks["moe"])
+        experts = {k: moe_p.pop(k) for k in MOE.EXPERT_WEIGHTS}
+        moe_p["layer"] = jnp.arange(cfg.num_layers - nk)
+        moe_blocks = dict(moe_blocks, moe=moe_p)
 
     def mk_body(dense_ffn):
         def body(carry, xs):
             h, aux, st = carry
             p, c = xs
+            if experts is not None and not dense_ffn:
+                p = dict(p, moe=dict(p["moe"], **experts))
             h, a, nc, s = moe_block(p, cfg, h, positions=positions, cache=c,
                                     cache_idx=cache_idx,
                                     token_mask=None if dense_ffn else mask)
@@ -482,7 +494,7 @@ def _run_moe(params, cfg, x, positions, cache, cache_idx, mode, new_cache):
     (x, aux_total, st), ncd = jax.lax.scan(
         mk_body(True), (x, aux_total, st), (params["dense_blocks"], lc_d))
     (x, aux_total, st), ncm = jax.lax.scan(
-        mk_body(False), (x, aux_total, st), (params["moe_blocks"], lc_m))
+        mk_body(False), (x, aux_total, st), (moe_blocks, lc_m))
     if new_cache is not None:
         new_cache["layers"] = jax.tree.map(
             lambda a, b2: jnp.concatenate([a, b2], axis=0), ncd, ncm)

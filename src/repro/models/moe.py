@@ -5,12 +5,15 @@ The router spans all ``num_experts`` routed experts: softmax over its
 logits, greedy top-k, the gates renormalised only where
 ``norm_topk_prob``, then scaled by ``routed_scaling_factor``.  The device
 holds experts ``[first_expert, first_expert + held)`` (expert parallelism;
-all of them by default) and computes their part of the result: every held
-expert runs on every token, and each output is weighted by the token's
-gate for that expert, 0 where the token did not route to it.  No
-assignment is ever dropped, at any token count.  The other devices' part
-is left out; on one device the layer runs without the exchange.  Shared
-experts (always on) are added on every device.
+all of them by default) and computes their part of the result: each
+output is weighted by the token's gate for that expert, 0 where the token
+did not route to it.  In a decode step (given the layer stacks and the
+layer's index) only the held experts some token routes to are read
+(``kernels.ops.moe_decode``, a grouped expert kernel on TPU); otherwise
+every held expert runs on every token.  No assignment is ever dropped, at
+any token count.  The other devices' part is left out; on one device the
+layer runs without the exchange.  Shared experts (always on) are added on
+every device.
 
 Expert ``e``'s weights are drawn from ``fold_in(<layer's expert key>, e)``,
 so a device that holds a share draws exactly the experts the whole layer
@@ -26,14 +29,19 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MoEConfig
+from repro.kernels import ops, ref
 from repro.models.layers import dense_init
 
 Params = Dict[str, Any]
 
 # route_stats entries, in order: assignments over all experts, those on
 # held experts, held experts with at least one assignment, held
-# assignments the layer computed
-ROUTE_STATS = ("routed", "routed_held", "experts_hit", "computed")
+# assignments whose expert the layer read, and held experts whose weights
+# the layer read (for every row, the uncounted ones too)
+ROUTE_STATS = ("routed", "routed_held", "experts_hit", "computed",
+               "experts_read")
+# a held expert's weights, stacked over the held experts
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
 def init_moe(key, d: int, cfg: MoEConfig, dtype) -> Params:
@@ -82,7 +90,10 @@ def moe_ffn(p: Params, x: jax.Array, cfg: MoEConfig, *,
             ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """x: (b, s, d) -> (y, aux_loss, route_stats).  ``token_mask`` (b, s)
     bool selects the tokens ``route_stats`` counts (int32, ordered as
-    ``ROUTE_STATS``); without it the stats are None."""
+    ``ROUTE_STATS``); without it the stats are None.  Where ``p`` holds a
+    ``layer`` index, its ``EXPERT_WEIGHTS`` are the whole stacks of the
+    MoE layers (``[L, held, ...]``) and this is that layer of them: only
+    the held experts some token routes to are read."""
     b, s, d = x.shape
     T = b * s
     x2 = x.reshape(T, d)
@@ -94,11 +105,13 @@ def moe_ffn(p: Params, x: jax.Array, cfg: MoEConfig, *,
                             dtype=jnp.float32)
     combine = jnp.einsum("tk,tkh->th", gates, assign)              # (T,held)
 
-    # every held expert on every token (dropless)
-    gate = jnp.einsum("td,hdf->htf", x2, p["w_gate"])
-    up = jnp.einsum("td,hdf->htf", x2, p["w_up"])
-    out = jnp.einsum("htf,hfd->htd", jax.nn.silu(gate) * up, p["w_down"])
-    y = jnp.einsum("htd,th->td", out.astype(jnp.float32), combine)
+    w = [p[k] for k in EXPERT_WEIGHTS]
+    if "layer" in p:
+        y, read = ops.moe_decode(x2, combine, *w, p["layer"])
+    else:
+        # every held expert on every token (dropless)
+        y = ref.moe_decode_ref(x2, combine, *w)
+        read = jnp.ones((cfg.held,), bool)
 
     if cfg.num_shared_experts:
         sp = p["shared"]
@@ -117,7 +130,8 @@ def moe_ffn(p: Params, x: jax.Array, cfg: MoEConfig, *,
             real.sum() * cfg.top_k,
             (on_held * real).sum(),
             (per_expert > 0).sum(),
-            # the assignments whose gate reached the combine above
-            ((combine > 0) * real).sum(),
+            # the assignments whose gate reached an expert that was read
+            ((combine > 0) * read * real).sum(),
+            read.sum(),
         ]).astype(jnp.int32)
     return y.reshape(b, s, d), aux, stats

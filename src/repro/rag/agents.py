@@ -18,9 +18,10 @@ from repro.configs.base import ModelConfig
 from repro.core.events import (CT_LM_FETCHES, CT_LM_KV_BYTES_RESERVED,
                                CT_LM_KV_BYTES_USED, CT_LM_PAD_ROWS,
                                CT_LM_REAL_TOKENS, CT_LM_STEPS, CT_MOE_DROPPED,
-                               CT_MOE_EXPERTS_HIT, CT_MOE_LAYER_STEPS,
-                               CT_MOE_ROUTED, CT_MOE_ROUTED_HELD, SP_LM_CALL,
-                               SP_LM_FETCH, SP_LM_STEP)
+                               CT_MOE_EXPERTS_HIT, CT_MOE_EXPERTS_READ,
+                               CT_MOE_LAYER_STEPS, CT_MOE_ROUTED,
+                               CT_MOE_ROUTED_HELD, SP_LM_CALL, SP_LM_FETCH,
+                               SP_LM_STEP)
 from repro.models import Model, build_model
 from repro.models.lm import MOE_STATS
 from repro.rag.embedder import CALL_WIDTH
@@ -129,6 +130,7 @@ class LMAgent:
                 spans.count(CT_MOE_ROUTED, got["routed"])
                 spans.count(CT_MOE_ROUTED_HELD, got["routed_held"])
                 spans.count(CT_MOE_EXPERTS_HIT, got["experts_hit"])
+                spans.count(CT_MOE_EXPERTS_READ, got["experts_read"])
                 spans.count(CT_MOE_LAYER_STEPS, got["layer_steps"])
                 spans.count(CT_MOE_DROPPED,
                             got["routed_held"] - got["computed"])

@@ -4,6 +4,7 @@ no q-LoRA and YaRN, a dropless MoE layer told which experts it holds, the
 chip's share of an expert-parallel layer, and the generator on the live
 pipeline.  CPU, small widths, float32, seeded weights."""
 import dataclasses
+import functools
 import importlib.util
 from pathlib import Path
 
@@ -285,24 +286,34 @@ def test_search_and_chat_are_one_model_prompted_in_its_vocabulary(pipe):
     assert max(big) >= LITE.vocab_size > max(own)
 
 
-def test_one_fetch_per_call_with_the_moe_counters(pipe):
-    from repro.core.events import (CT_LM_FETCHES, CT_MOE_DROPPED,
-                                   CT_MOE_EXPERTS_HIT, CT_MOE_LAYER_STEPS,
-                                   CT_MOE_ROUTED, CT_MOE_ROUTED_HELD,
-                                   SP_LM_CALL)
+def _moe_counters(agent):
+    """Two calls of ``agent`` (3 rows for 5 tokens, then 1 row for 4)
+    under the span recorder -> (served tokens, lm.call spans, counters)."""
+    from repro.core.events import SP_LM_CALL
     from repro.serving import spans
 
-    agent, cfg = pipe.chat, pipe.models["chat"][0]
     prompt = list(range(4, 20))
     spans.enable()
     spans.clear()
     try:
-        agent.generate_batch([prompt] * 3, max_new=5)
-        agent.generate(prompt, max_new=4, stop_at_eos=False)
+        served = [r.token_ids for r in
+                  agent.generate_batch([prompt] * 3, max_new=5)]
+        served.append(agent.generate(prompt, max_new=4,
+                                     stop_at_eos=False).token_ids)
         recorded, counters = spans.drain()
     finally:
         spans.disable()
-    calls = [s for s in recorded if s.name == SP_LM_CALL]
+    return served, [s for s in recorded if s.name == SP_LM_CALL], counters
+
+
+def test_one_fetch_per_call_with_the_moe_counters(pipe):
+    from repro.core.events import (CT_LM_FETCHES, CT_MOE_DROPPED,
+                                   CT_MOE_EXPERTS_HIT, CT_MOE_EXPERTS_READ,
+                                   CT_MOE_LAYER_STEPS, CT_MOE_ROUTED,
+                                   CT_MOE_ROUTED_HELD)
+
+    agent, cfg = pipe.chat, pipe.models["chat"][0]
+    _, calls, counters = _moe_counters(agent)
     assert len(calls) == 2 and counters[CT_LM_FETCHES] == 2
     assert [(s.attrs["role"], s.attrs["rows"], s.attrs["steps"])
             for s in calls] == [("chat", 3, 4), ("chat", 1, 3)]
@@ -315,3 +326,41 @@ def test_one_fetch_per_call_with_the_moe_counters(pipe):
     assert 0 <= counters[CT_MOE_EXPERTS_HIT] <= n_moe * steps \
         * cfg.moe.num_experts_held
     assert counters[CT_MOE_DROPPED] == 0
+    # off the chip the decode step runs the dense-over-held reference,
+    # which reads every held expert
+    assert counters[CT_MOE_EXPERTS_HIT] <= counters[CT_MOE_EXPERTS_READ] \
+        == n_moe * steps * cfg.moe.num_experts_held
+
+
+def test_the_grouped_kernel_serves_the_same_tokens_reading_fewer_experts(
+        monkeypatch):
+    """The decode steps on the grouped expert kernel (interpret mode), as
+    on a chip, against the dense-over-held reference: the same served
+    tokens, one fetch a call, nothing dropped, and the held experts read
+    at least those the real rows hit and, at these seeds, fewer than
+    every held one (9 and 14)."""
+    from repro.core.events import (CT_LM_FETCHES, CT_MOE_DROPPED,
+                                   CT_MOE_EXPERTS_HIT, CT_MOE_EXPERTS_READ,
+                                   CT_MOE_LAYER_STEPS, CT_MOE_ROUTED,
+                                   CT_MOE_ROUTED_HELD)
+    from repro.kernels import ops
+    from repro.rag.agents import LMAgent
+
+    cfg = small(2, 1)
+    params = build_model(cfg).init(jax.random.PRNGKey(4))
+    want, _, ref_counters = _moe_counters(
+        LMAgent(cfg, params, max_len=64, role="chat"))
+    monkeypatch.setattr(ops, "moe_decode", functools.partial(
+        ops.moe_decode, use_pallas=True))
+    served, calls, counters = _moe_counters(
+        LMAgent(cfg, params, max_len=64, role="chat"))
+    assert served == want
+    assert len(calls) == 2 and counters[CT_LM_FETCHES] == 2
+    for name in (CT_MOE_ROUTED, CT_MOE_ROUTED_HELD, CT_MOE_EXPERTS_HIT,
+                 CT_MOE_LAYER_STEPS):
+        assert counters[name] == ref_counters[name]
+    held_reads = counters[CT_MOE_LAYER_STEPS] * cfg.moe.held
+    assert ref_counters[CT_MOE_EXPERTS_READ] == held_reads
+    assert counters[CT_MOE_EXPERTS_HIT] <= counters[CT_MOE_EXPERTS_READ] \
+        < held_reads
+    assert counters[CT_MOE_DROPPED] == ref_counters[CT_MOE_DROPPED] == 0
