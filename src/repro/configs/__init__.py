@@ -6,8 +6,7 @@ from typing import Dict, List
 
 from repro.configs.base import (  # noqa: F401 (re-export)
     MLAConfig, MoEConfig, ModelConfig, SSMConfig, EncDecConfig, VLMConfig,
-    YarnConfig, ShapeConfig, SHAPES, SHAPES_BY_NAME, reduced,
-    shape_applicable)
+    YarnConfig, reduced)
 
 _ARCH_MODULES: Dict[str, str] = {
     "deepseek-v3-671b": "repro.configs.deepseek_v3_671b",

@@ -1,10 +1,9 @@
 """Configuration dataclasses for the repro framework.
 
 One ``ModelConfig`` describes any architecture in the assigned pool (dense /
-MoE+MLA / Mamba2-hybrid / xLSTM / enc-dec audio / VLM).  ``ShapeConfig``
-describes one (seq_len, global_batch, kind) input-shape cell of the dry-run
-matrix.  ``reduced()`` shrinks a config for CPU smoke tests while keeping the
-family topology (MoE stays MoE, hybrid stays hybrid, ...).
+MoE+MLA / Mamba2-hybrid / xLSTM / enc-dec audio / VLM).  ``reduced()``
+shrinks a config for CPU smoke tests while keeping the family topology (MoE
+stays MoE, hybrid stays hybrid, ...).
 """
 from __future__ import annotations
 
@@ -149,17 +148,14 @@ class ModelConfig:
     ssm: SSMConfig = field(default_factory=SSMConfig)
     encdec: EncDecConfig = field(default_factory=EncDecConfig)
     vlm: VLMConfig = field(default_factory=VLMConfig)
-    # DeepSeek-v3 multi-token prediction depth (extra MTP module count)
-    mtp_depth: int = 0
     # numerics
     dtype: str = "bfloat16"
-    # remat policy for training: none | dots | full
-    remat: str = "dots"
     # attention implementation for train/prefill: "reference" materializes
     # the full score matrix; "chunked" is the flash-pattern online-softmax
     # scan over KV blocks (§Perf iteration 1 — memory-roofline fix)
     attn_impl: str = "reference"
-    # sub-quadratic? (drives long_500k eligibility)
+    # sub-quadratic? (a hybrid's attention takes a sliding window at
+    # long context: lm._window_for)
     subquadratic: bool = False
 
     @property
@@ -172,8 +168,8 @@ class ModelConfig:
 
     def param_count(self) -> int:
         """Analytic parameter count (the constructed pytree but the final
-        norm's d scales; used for roofline MODEL_FLOPS = 6*N*D and the perf
-        model).  Of a MoE layer it counts the routed experts held here."""
+        norm's d scales; used for model FLOPs and the perf model).  Of a
+        MoE layer it counts the routed experts held here."""
         d, L, V = self.d_model, self.num_layers, self.vocab_size
         n = V * d  # embedding
         if not self.tie_embeddings:
@@ -189,13 +185,10 @@ class ModelConfig:
             # encoder stack (self-attn + FFN + norms per layer)
             n += self.encdec.encoder_layers * (
                 self._dense_attn_params() + self._mlp_params(self.d_ff) + 2 * d)
-        if self.mtp_depth:
-            # each MTP module: one extra transformer layer + projection
-            n += self.mtp_depth * (self._attn_params(L - 1) + self._ffn_params(0) + d * 2 * d)
         return n
 
     def active_param_count(self) -> int:
-        """Active (per-token) parameters — for MoE roofline MODEL_FLOPS.
+        """Active (per-token) parameters — for a MoE model's FLOPs.
         With a share of the experts held (``moe.num_experts_held``) a
         token's routed experts lie on this device top_k · held / E times
         on average, and only those count."""
@@ -278,35 +271,6 @@ class ModelConfig:
         return self.vlm.enabled and (layer % self.vlm.cross_attn_every == 0)
 
 
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                          # "train" | "prefill" | "decode"
-
-    @property
-    def is_decode(self) -> bool:
-        return self.kind == "decode"
-
-
-SHAPES: Tuple[ShapeConfig, ...] = (
-    ShapeConfig("train_4k", 4096, 256, "train"),
-    ShapeConfig("prefill_32k", 32768, 32, "prefill"),
-    ShapeConfig("decode_32k", 32768, 128, "decode"),
-    ShapeConfig("long_500k", 524288, 1, "decode"),
-)
-
-SHAPES_BY_NAME = {s.name: s for s in SHAPES}
-
-
-def shape_applicable(cfg: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
-    """Whether an (arch, shape) cell runs, and the reason if skipped."""
-    if shape.name == "long_500k" and not cfg.subquadratic:
-        return False, "long_500k needs sub-quadratic attention; arch is full-attention"
-    return True, ""
-
-
 def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 64,
             vocab: int = 256) -> ModelConfig:
     """Shrink a config for CPU smoke tests, preserving family topology."""
@@ -316,7 +280,7 @@ def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 64,
     changes = dict(
         num_layers=layers, d_model=d_model, num_heads=heads, num_kv_heads=kv,
         head_dim=d_model // heads, d_ff=(128 if cfg.d_ff else 0),
-        vocab_size=vocab, max_seq_len=4096, dtype="float32", remat="none",
+        vocab_size=vocab, max_seq_len=4096, dtype="float32",
     )
     if cfg.moe.enabled:
         m = cfg.moe
@@ -345,6 +309,4 @@ def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 64,
     if cfg.vlm.enabled:
         changes["vlm"] = dataclasses.replace(
             cfg.vlm, cross_attn_every=2, vision_tokens=8, vision_dim=d_model)
-    if cfg.mtp_depth:
-        changes["mtp_depth"] = 1
     return dataclasses.replace(cfg, **changes)

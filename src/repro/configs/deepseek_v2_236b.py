@@ -17,5 +17,4 @@ CONFIG = ModelConfig(
                   d_ff=1536, first_k_dense=1, dense_d_ff=12288),
     mla=MLAConfig(kv_lora_rank=512, q_lora_rank=1536,
                   qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128),
-    remat="full",
 )

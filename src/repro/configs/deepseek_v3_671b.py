@@ -1,4 +1,4 @@
-"""deepseek-v3-671b [moe] — MLA, 1 shared + 256 routed top-8, MTP.
+"""deepseek-v3-671b [moe] — MLA, 1 shared + 256 routed top-8.
 [arXiv:2412.19437; hf]  61L d_model=7168 128H (GQA kv=128) expert d_ff=2048
 vocab=129280.
 """
@@ -19,6 +19,4 @@ CONFIG = ModelConfig(
                   d_ff=2048, first_k_dense=3, dense_d_ff=18432),
     mla=MLAConfig(kv_lora_rank=512, q_lora_rank=1536,
                   qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128),
-    mtp_depth=1,
-    remat="full",
 )

@@ -13,5 +13,4 @@ CONFIG = ModelConfig(
     head_dim=128,
     d_ff=28672,
     vocab_size=32768,
-    remat="full",
 )

@@ -1,7 +1,7 @@
 """whisper-large-v3 [audio] — enc-dec, conv frontend (stub).
 [arXiv:2212.04356; unverified]
 32L d_model=1280 20H (kv=20) d_ff=5120 vocab=51866.
-The conv/mel frontend is a STUB: input_specs() supplies precomputed frame
+The conv/mel frontend is a STUB: the caller supplies precomputed frame
 embeddings of shape (batch, source_positions, d_model).
 """
 from repro.configs.base import EncDecConfig, ModelConfig

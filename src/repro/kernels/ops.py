@@ -1,9 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
 ``use_pallas`` dispatch: on TPU backends the Pallas kernels run natively;
-on CPU (this container) they run via interpret mode when explicitly
-requested, otherwise the jnp reference executes.  The dry-run lowers the
-reference path so cost_analysis() sees the real FLOPs.
+on CPU they run in interpret mode when explicitly requested, otherwise the
+jnp reference executes.
 """
 from __future__ import annotations
 

@@ -1,1 +1,1 @@
-from repro.models.model import Model, build_model, input_specs, make_step_fn  # noqa: F401
+from repro.models.model import Model, build_model  # noqa: F401

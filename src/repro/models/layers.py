@@ -15,8 +15,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.sharding import constrain
-
 Params = Dict[str, Any]
 
 
@@ -166,8 +164,8 @@ def mha_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     (…, sq, sk) score matrix — the memory-roofline fix for long-sequence
     train/prefill (§Perf iteration 1); exact (not approximate).
 
-    On TPU the Pallas flash kernel replaces this; the XLA form keeps the
-    dry-run roofline honest and is the CPU-correct fallback."""
+    On TPU the Pallas flash kernel replaces this; the XLA form is the
+    CPU-correct fallback."""
     b, sq, h, e = q.shape
     sk, n = k.shape[1], k.shape[2]
     g = h // n
@@ -225,8 +223,7 @@ def attention(p: Params, x: jax.Array, *, positions: jax.Array,
     kv_override: precomputed (k, v) for cross-attention (no rope on kv).
     """
     dtype = x.dtype
-    q = constrain(jnp.einsum("bsd,dhe->bshe", x, p["wq"]),
-                  ("batch", None, "model", None))
+    q = jnp.einsum("bsd,dhe->bshe", x, p["wq"])
     if "bq" in p:
         q = q + p["bq"]
     if kv_override is not None:
@@ -292,5 +289,4 @@ def mlp(p: Params, x: jax.Array) -> jax.Array:
         h = jax.nn.silu(gate) * up
     else:
         h = jax.nn.gelu(up)
-    h = constrain(h, ("batch", None, "model"))
     return jnp.einsum("bsf,fd->bsd", h, p["w_down"])

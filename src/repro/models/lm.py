@@ -3,8 +3,8 @@
 Design notes:
 - Pure functional: ``init_params`` builds a pytree, ``apply`` runs it.
 - Homogeneous layer stacks are **scanned** (stacked params with a leading
-  layer dim) — O(1) HLO size in depth, which keeps 100-layer dry-run
-  compiles tractable and is what production JAX frameworks do.
+  layer dim) — O(1) HLO size in depth, which keeps deep models' compiles
+  tractable and is what production JAX frameworks do.
 - One code path serves train / prefill / decode, switched by whether a
   cache pytree is provided.  Caches for scanned stacks are stacked arrays
   fed through ``lax.scan`` xs/ys.
@@ -24,7 +24,6 @@ from repro.models import mla as MLA
 from repro.models import moe as MOE
 from repro.models import ssm as SSM
 from repro.models import xlstm as XL
-from repro.models.sharding import constrain
 
 Params = Dict[str, Any]
 
@@ -37,15 +36,6 @@ MOE_STATS = MOE.ROUTE_STATS + ("layer_steps",)
 
 def _stack_init(fn, key, n: int):
     return jax.vmap(fn)(jax.random.split(key, n))
-
-
-def _remat(fn, cfg: ModelConfig, mode: str):
-    if mode != "train" or cfg.remat == "none":
-        return fn
-    if cfg.remat == "dots":
-        pol = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        return jax.checkpoint(fn, policy=pol)
-    return jax.checkpoint(fn)
 
 
 # ---------------------------------------------------------------------------
@@ -80,7 +70,7 @@ def dense_block(p: Params, cfg: ModelConfig, x, *, positions, causal=True,
     h, new_cache = _attend(p["attn"], cfg, L.rmsnorm(p["ln1"], x, cfg.norm_eps),
                            positions=positions, causal=causal, cache=cache,
                            cache_idx=cache_idx, window=window)
-    x = constrain(x + h, ("batch", None, None))
+    x = x + h
     new_cross = None
     if "xattn" in p and (cross_kv is not None or cross_cache is not None):
         if cross_cache is not None:
@@ -95,8 +85,7 @@ def dense_block(p: Params, cfg: ModelConfig, x, *, positions, causal=True,
                            positions=positions, theta=cfg.rope_theta,
                            kv_override=kv)
         x = x + jnp.tanh(p["xgate"]).astype(x.dtype) * h
-    x = constrain(x + L.mlp(p["mlp"], L.rmsnorm(p["ln2"], x, cfg.norm_eps)),
-                  ("batch", None, None))
+    x = x + L.mlp(p["mlp"], L.rmsnorm(p["ln2"], x, cfg.norm_eps))
     return x, new_cache, new_cross
 
 
@@ -167,7 +156,7 @@ def moe_block(p: Params, cfg: ModelConfig, x, *, positions, cache=None,
                                     token_mask=token_mask)
     else:
         y = L.mlp(p["mlp"], h2)
-    return constrain(x + y, ("batch", None, None)), aux, new_cache, stats
+    return x + y, aux, new_cache, stats
 
 
 # ---------------------------------------------------------------------------
@@ -207,12 +196,6 @@ def init_params(key, cfg: ModelConfig) -> Params:
         params["moe_blocks"] = _stack_init(
             lambda k: init_moe_block(k, cfg, dense_ffn=False), keys[3],
             cfg.num_layers - nk)
-        if cfg.mtp_depth:
-            params["mtp"] = {
-                "proj": L.dense_init(keys[4], (2 * d, d), dtype),
-                "ln": L.init_rmsnorm(d, dtype),
-                "block": init_moe_block(keys[5], cfg, dense_ffn=True),
-            }
     elif fam == "hybrid":
         params["blocks"] = _stack_init(
             lambda k: {"ln": L.init_rmsnorm(d, dtype),
@@ -335,7 +318,6 @@ def apply(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
     tokens = batch["tokens"]
     b, s = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)
-    x = constrain(x, ("batch", None, None))
     cache_idx = cache["idx"] if cache is not None else None
     positions = (jnp.arange(s) if cache is None
                  else cache_idx + jnp.arange(s))
@@ -358,7 +340,7 @@ def apply(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
                                      cache_idx, mode, new_cache)
     elif fam == "hybrid":
         x, new_cache = _run_hybrid(params, cfg, x, positions, cache,
-                                   cache_idx, mode, new_cache)
+                                   cache_idx, new_cache)
     elif fam == "ssm":
         x, new_cache = _run_xlstm(params, cfg, x, cache, mode, new_cache)
     elif fam == "audio":
@@ -374,13 +356,11 @@ def apply(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
 
 def _logits(params: Params, x: jax.Array) -> jax.Array:
     if "lm_head" in params:
-        return constrain(jnp.einsum("bsd,dv->bsv", x, params["lm_head"]),
-                         ("batch", None, "model"))
+        return jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
     # tied embeddings: scale logits by 1/sqrt(d) (Gemma-style) since the
     # embedding table is unit-scale
     d = x.shape[-1]
-    logits = jnp.einsum("bsd,vd->bsv", x, params["embed"]) * (d ** -0.5)
-    return constrain(logits, ("batch", None, "model"))
+    return jnp.einsum("bsd,vd->bsv", x, params["embed"]) * (d ** -0.5)
 
 
 def _run_dense_stack(stacked: Params, cfg: ModelConfig, x, positions,
@@ -403,7 +383,7 @@ def _run_dense_stack(stacked: Params, cfg: ModelConfig, x, positions,
         return (h, cs), None
 
     n_layers = jax.tree.leaves(stacked)[0].shape[0]
-    (x, caches), _ = jax.lax.scan(_remat(body, cfg, mode), (x, caches),
+    (x, caches), _ = jax.lax.scan(body, (x, caches),
                                   (stacked, jnp.arange(n_layers)))
     return x, caches
 
@@ -438,7 +418,6 @@ def _run_vlm(params, cfg, batch, x, positions, cache, cache_idx, mode,
         h, nsc = jax.lax.scan(inner, h, (g["selfs"], c_selfs))
         return h, (ncc, nsc, nxkv)
 
-    body = _remat(body, cfg, mode)
     n_groups = cfg.num_layers // every
     # reshape self caches (n_groups*per_group, ...) -> (n_groups, per_group,...)
     sc_g = (None if sc is None else
@@ -484,7 +463,7 @@ def _run_moe(params, cfg, x, positions, cache, cache_idx, mode, new_cache):
                                     cache_idx=cache_idx,
                                     token_mask=None if dense_ffn else mask)
             return (h, aux + a, st if s is None else st + s), nc
-        return _remat(body, cfg, mode)
+        return body
 
     lc = None if cache is None else cache["layers"]
     lc_d = None if lc is None else jax.tree.map(lambda a: a[:nk], lc)
@@ -505,7 +484,7 @@ def _run_moe(params, cfg, x, positions, cache, cache_idx, mode, new_cache):
     return x, aux_total, new_cache
 
 
-def _run_hybrid(params, cfg, x, positions, cache, cache_idx, mode, new_cache):
+def _run_hybrid(params, cfg, x, positions, cache, cache_idx, new_cache):
     every = cfg.ssm.attn_every
     n_attn = cfg.num_layers // every
     # ring caches are allocated at exactly the window size
@@ -523,8 +502,6 @@ def _run_hybrid(params, cfg, x, positions, cache, cache_idx, mode, new_cache):
             init_state=st, return_state=st is not None)
         return h + y, nst
 
-    mamba_body = _remat(mamba_body, cfg, mode)
-
     # scan groups of `every` mamba layers, then the weight-shared attn block
     n_groups = cfg.num_layers // every
     rem = cfg.num_layers - n_groups * every
@@ -536,8 +513,6 @@ def _run_hybrid(params, cfg, x, positions, cache, cache_idx, mode, new_cache):
         h, na, _ = dense_block(params["shared"], cfg, h, positions=positions,
                                cache=a_cache, cache_idx=cache_idx, window=W)
         return h, (n_states, na)
-
-    group_body = _remat(group_body, cfg, mode)
 
     def split_groups(tree, n, size):
         return jax.tree.map(
@@ -615,7 +590,6 @@ def _run_audio(params, cfg, batch, x, positions, cache, cache_idx, mode,
                                   causal=False)
             return h, None
 
-        enc_body = _remat(enc_body, cfg, mode)
         mem, _ = jax.lax.scan(enc_body, mem, params["encoder"]["blocks"])
         memory = L.rmsnorm(params["encoder"]["final_norm"], mem, cfg.norm_eps)
 
@@ -632,7 +606,6 @@ def _run_audio(params, cfg, batch, x, positions, cache, cache_idx, mode,
                                   cross_cache=xc)
         return h, (nc, nxkv)
 
-    body = _remat(body, cfg, mode)
     x, (nlc, nxkv) = jax.lax.scan(body, x, (params["blocks"], lc, xkv))
     if new_cache is not None:
         new_cache["layers"] = nlc
@@ -640,44 +613,3 @@ def _run_audio(params, cfg, batch, x, positions, cache, cache_idx, mode,
             new_cache["cross_kv"] = nxkv
     return x, new_cache
 
-
-# ---------------------------------------------------------------------------
-# loss
-# ---------------------------------------------------------------------------
-
-def cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
-    """Shard-friendly CE: the gold logit is extracted with a fused one-hot
-    contraction instead of take_along_axis — a dynamic gather over the
-    vocab dim would force GSPMD to all-gather the full logits tensor
-    (hundreds of GB at train_4k shapes)."""
-    logits = logits.astype(jnp.float32)
-    m = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
-    shifted = logits - m
-    lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1)) + m[..., 0]
-    onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=logits.dtype)
-    gold = jnp.sum(shifted * onehot, axis=-1) + m[..., 0]
-    return jnp.mean(lse - gold)
-
-
-def loss_fn(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array]
-            ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    logits, aux, _ = apply(params, cfg, batch, mode="train")
-    labels = batch["labels"]
-    ce = cross_entropy(logits[:, :-1], labels[:, 1:])
-    loss = ce + aux
-    metrics = {"ce": ce, "aux": aux}
-    if cfg.mtp_depth and "mtp" in params:
-        mtp = params["mtp"]
-        h = jnp.take(params["embed"], batch["tokens"][:, 1:], axis=0)
-        h0 = L.rmsnorm(mtp["ln"],
-                       jnp.take(params["embed"], batch["tokens"][:, :-1],
-                                axis=0), cfg.norm_eps)
-        h = jnp.einsum("bsd,dk->bsk", jnp.concatenate([h0, h], -1),
-                       mtp["proj"])
-        pos = jnp.arange(h.shape[1])
-        h, _, _, _ = moe_block(mtp["block"], cfg, h, positions=pos)
-        mtp_logits = _logits(params, h)
-        mtp_ce = cross_entropy(mtp_logits[:, :-1], labels[:, 2:])
-        loss = loss + 0.3 * mtp_ce
-        metrics["mtp_ce"] = mtp_ce
-    return loss, metrics

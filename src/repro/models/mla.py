@@ -21,7 +21,6 @@ import numpy as np
 
 from repro.configs.base import MLAConfig, YarnConfig
 from repro.models.layers import apply_rope, dense_init, init_rmsnorm, rmsnorm
-from repro.models.sharding import constrain
 
 Params = Dict[str, Any]
 
@@ -100,7 +99,6 @@ def _project_q(p: Params, x: jax.Array, m: MLAConfig, positions, theta):
     else:
         ql = rmsnorm(p["q_norm"], jnp.einsum("bsd,dr->bsr", x, p["wq_a"]))
         q = jnp.einsum("bsr,rhe->bshe", ql, p["wq_b"])
-    q = constrain(q, ("batch", None, "model", None))
     q_nope = q[..., : m.qk_nope_head_dim]
     q_rope = _rope(q[..., m.qk_nope_head_dim:], positions, theta, m)
     return q_nope, q_rope

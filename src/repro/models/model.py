@@ -1,17 +1,12 @@
-"""Public model facade: build_model(cfg) + input_specs(cfg, shape).
-
-``input_specs`` returns ShapeDtypeStruct stand-ins for every model input of a
-given (arch, shape) cell — weak-type-correct, shardable, no allocation — used
-by the multi-pod dry-run and the roofline extraction.
-"""
+"""Public model facade: ``build_model(cfg)`` binds a config to the
+functional model in ``lm``."""
 from __future__ import annotations
 
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig, ShapeConfig
+from repro.configs.base import ModelConfig
 from repro.models import lm
 
 Params = Dict[str, Any]
@@ -21,8 +16,6 @@ class Model(NamedTuple):
     cfg: ModelConfig
     init: Callable[[jax.Array], Params]
     apply: Callable[..., Tuple[jax.Array, jax.Array, Optional[Params]]]
-    loss_fn: Callable[[Params, Dict[str, jax.Array]],
-                      Tuple[jax.Array, Dict[str, jax.Array]]]
     prefill: Callable[..., Tuple[jax.Array, Params]]
     decode_step: Callable[..., Tuple[jax.Array, Params]]
     init_cache: Callable[[int, int], Params]
@@ -34,9 +27,6 @@ def build_model(cfg: ModelConfig) -> Model:
 
     def apply(params, batch, *, mode="train", cache=None):
         return lm.apply(params, cfg, batch, mode=mode, cache=cache)
-
-    def loss_fn(params, batch):
-        return lm.loss_fn(params, cfg, batch)
 
     def prefill(params, batch, cache):
         logits, _, new_cache = lm.apply(params, cfg, batch, mode="prefill",
@@ -54,69 +44,5 @@ def build_model(cfg: ModelConfig) -> Model:
     def init_cache(batch_size, max_len):
         return lm.init_cache(cfg, batch_size, max_len)
 
-    return Model(cfg, init, apply, loss_fn, prefill, decode_step, init_cache)
+    return Model(cfg, init, apply, prefill, decode_step, init_cache)
 
-
-# ---------------------------------------------------------------------------
-# dry-run input specs
-# ---------------------------------------------------------------------------
-
-def _sds(shape, dtype):
-    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
-
-
-def input_specs(cfg: ModelConfig, shape: ShapeConfig) -> Dict[str, Any]:
-    """ShapeDtypeStruct stand-ins for one (arch × shape) dry-run cell.
-
-    train/prefill: {"batch": {...}}.
-    decode: {"tokens": ..., "cache": <full cache spec at seq_len>}.
-    """
-    B, S = shape.global_batch, shape.seq_len
-    dt = cfg.dtype
-
-    def frontends(b):
-        ex = {}
-        if cfg.vlm.enabled:
-            ex["vision_embeds"] = _sds((b, cfg.vlm.vision_tokens,
-                                        cfg.vlm.vision_dim), dt)
-        if cfg.encdec.enabled:
-            ex["audio_frames"] = _sds((b, cfg.encdec.source_positions,
-                                       cfg.d_model), dt)
-        return ex
-
-    if shape.kind == "train":
-        batch = {"tokens": _sds((B, S), jnp.int32),
-                 "labels": _sds((B, S), jnp.int32), **frontends(B)}
-        return {"batch": batch}
-    if shape.kind == "prefill":
-        batch = {"tokens": _sds((B, S), jnp.int32), **frontends(B)}
-        return {"batch": batch}
-    # decode: one new token against a cache of length S
-    cache = jax.eval_shape(lambda: lm.init_cache(cfg, B, S))
-    return {"tokens": _sds((B, 1), jnp.int32), "cache": cache}
-
-
-def make_step_fn(cfg: ModelConfig, shape: ShapeConfig):
-    """The function the dry-run lowers for this cell: train_step(grad) for
-    train shapes, forward for prefill, serve_step for decode shapes."""
-    model = build_model(cfg)
-
-    if shape.kind == "train":
-        def train_fwd(params, batch):
-            loss, _ = model.loss_fn(params, batch)
-            return loss
-
-        def train_step(params, batch):
-            loss, grads = jax.value_and_grad(train_fwd)(params, batch)
-            return loss, grads
-        return train_step
-
-    if shape.kind == "prefill":
-        def prefill_step(params, batch):
-            logits, _, _ = model.apply(params, batch, mode="train")
-            return logits[:, -1]
-        return prefill_step
-
-    def serve_step(params, tokens, cache):
-        return model.decode_step(params, tokens, cache)
-    return serve_step

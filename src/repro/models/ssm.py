@@ -1,4 +1,4 @@
-"""Mamba2 (SSD) block: chunked-scan training/prefill + O(1) decode step.
+"""Mamba2 (SSD) block: chunked-scan forward/prefill + O(1) decode step.
 
 Chunked scan follows the SSD formulation (Dao & Gu, 2024): the sequence is
 split into chunks of ``Q`` tokens; the intra-chunk term is a masked
@@ -17,7 +17,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import SSMConfig
 from repro.models.layers import dense_init
-from repro.models.sharding import constrain
 
 Params = Dict[str, Any]
 
@@ -93,10 +92,8 @@ def mamba2_forward(p: Params, x: jax.Array, s: SSMConfig, *,
     nc = l // Q
     dtype = x.dtype
 
-    z = constrain(jnp.einsum("bld,de->ble", x, p["wz"]),
-                  ("batch", None, "model"))
-    xc = constrain(jnp.einsum("bld,de->ble", x, p["wx"]),
-                   ("batch", None, "model"))
+    z = jnp.einsum("bld,de->ble", x, p["wz"])
+    xc = jnp.einsum("bld,de->ble", x, p["wx"])
     Bc = jnp.einsum("bld,de->ble", x, p["wB"])
     Cc = jnp.einsum("bld,de->ble", x, p["wC"])
     dt = jax.nn.softplus(

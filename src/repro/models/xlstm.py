@@ -15,7 +15,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import SSMConfig
 from repro.models.layers import dense_init
-from repro.models.sharding import constrain
 
 Params = Dict[str, Any]
 
@@ -108,20 +107,11 @@ def mlstm_forward(p: Params, x: jax.Array, s: SSMConfig, *,
         valid = (jnp.arange(l) < l_real)[None, :, None]
         ig = jnp.where(valid, ig, -1e30)
         fg = jnp.where(valid, fg, 30.0)   # log_sigmoid(30) ~ 0
-    # mLSTM heads (H=4) cannot shard a 16-way model axis; forcing the
-    # projections model-sharded makes every chunk-scan step all-gather.
-    # Gather ONCE here (replicated inner activations) instead — §Perf
-    # hillclimb B: collective term -6x at prefill.  Single-token decode
-    # keeps the sharded layout (replication costs more than it saves).
-    inner_spec = ("batch", None, None) if l_real > 1 else \
-        ("batch", None, "model")
     xq, new_conv = _causal_conv(
-        constrain(jnp.einsum("bld,de->ble", x, p["wq"]), inner_spec),
-        p["conv"], conv_s, state_len=l_real)
-    k = constrain(jnp.einsum("bld,de->ble", x, p["wk"]),
-                  inner_spec).reshape(b, l, H, P)
-    v = constrain(jnp.einsum("bld,de->ble", x, p["wv"]),
-                  inner_spec).reshape(b, l, H, P)
+        jnp.einsum("bld,de->ble", x, p["wq"]), p["conv"], conv_s,
+        state_len=l_real)
+    k = jnp.einsum("bld,de->ble", x, p["wk"]).reshape(b, l, H, P)
+    v = jnp.einsum("bld,de->ble", x, p["wv"]).reshape(b, l, H, P)
     q = xq.reshape(b, l, H, P)
 
     if init_state is not None:

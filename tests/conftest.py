@@ -1,8 +1,7 @@
 import jax
 import pytest
 
-# Tests run on the single real CPU device — the 512-device override is
-# strictly dryrun.py-local (per the harness contract).
+# Tests run on the single real CPU device.
 jax.config.update("jax_enable_x64", False)
 
 

@@ -1,6 +1,6 @@
-"""Per-architecture smoke tests: reduced config, one forward + one train
-step on CPU, asserting output shapes and finiteness; prefill+decode
-consistency against the full-sequence forward."""
+"""Per-architecture smoke tests: reduced config, one forward on CPU,
+asserting output shapes and finiteness; prefill+decode consistency against
+the full-sequence forward."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -18,7 +18,6 @@ ARCHS = [pytest.param(a, marks=pytest.mark.slow) if a in SLOW_ARCHS else a
 
 def _batch(cfg, B, S, key):
     batch = {"tokens": jax.random.randint(key, (B, S), 0, cfg.vocab_size)}
-    batch["labels"] = batch["tokens"]
     if cfg.vlm.enabled:
         batch["vision_embeds"] = jax.random.normal(
             key, (B, cfg.vlm.vision_tokens, cfg.vlm.vision_dim))
@@ -29,7 +28,7 @@ def _batch(cfg, B, S, key):
 
 
 @pytest.mark.parametrize("arch", ARCHS)
-def test_smoke_forward_and_train_step(arch, rng):
+def test_smoke_forward(arch, rng):
     cfg = reduced(get_config(arch))
     model = build_model(cfg)
     params = model.init(rng)
@@ -38,10 +37,6 @@ def test_smoke_forward_and_train_step(arch, rng):
     logits, aux, _ = model.apply(params, batch, mode="train")
     assert logits.shape == (B, S, cfg.vocab_size)
     assert bool(jnp.isfinite(logits).all())
-    (loss, metrics), grads = jax.value_and_grad(
-        model.loss_fn, has_aux=True)(params, batch)
-    assert bool(jnp.isfinite(loss))
-    assert all(bool(jnp.isfinite(g).all()) for g in jax.tree.leaves(grads))
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -52,8 +47,7 @@ def test_prefill_decode_matches_forward(arch, rng):
     B, S, P = 2, 40, 32
     batch = _batch(cfg, B, S, jax.random.fold_in(rng, 2))
     toks = batch["tokens"]
-    extras = {k: v for k, v in batch.items()
-              if k not in ("tokens", "labels")}
+    extras = {k: v for k, v in batch.items() if k != "tokens"}
     full, _, _ = model.apply(params, batch, mode="train")
     cache = model.init_cache(B, S)
     pre, cache = model.prefill(params, {"tokens": toks[:, :P], **extras},

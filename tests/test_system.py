@@ -108,15 +108,40 @@ def test_tpu_slice_deployment_runs():
     assert res.makespan < 5.0          # a pod is far faster than a phone
 
 
+# launch/serve.py main() modes, each at tiny widths on the live runtime
+SERVE_MODES = {
+    "w1": ["--workflow", "1", "--queries", "1"],
+    "w2": ["--workflow", "2", "--queries", "1"],
+    "w3": ["--workflow", "3", "--queries", "1"],
+    "serve": ["--serve", "--queries", "2"],
+    # the second query arrives after the first has finished its prefills,
+    # so they are resident to hit
+    "prefix-cache": ["--prefix-cache", "--queries", "2",
+                     "--inter-arrival", "4"],
+    "spec-decode": ["--spec-decode", "--queries", "1"],
+    "deepseek-v2-lite": ["--family", "deepseek-v2-lite", "--queries", "1"],
+    # MLA latent pages in the prefix cache
+    "prefix-cache-deepseek": ["--prefix-cache", "--family",
+                              "deepseek-v2-lite", "--queries", "2",
+                              "--inter-arrival", "4"],
+}
+
+
 @pytest.mark.slow
-def test_executable_pipeline_end_to_end():
+@pytest.mark.parametrize("mode", list(SERVE_MODES))
+def test_executable_pipeline_end_to_end(mode, monkeypatch, capsys):
     """The real JAX pipeline (tiny models) under the HeRo wall-clock
-    runtime: chunk -> embed -> index -> search -> rerank -> agents -> chat."""
-    import sys
+    runtime: chunk -> embed -> index -> search -> rerank -> agents -> chat,
+    driven through the launcher's command line; every query completes."""
     import repro.launch.serve as serve_mod
-    argv = sys.argv
-    sys.argv = ["serve", "--workflow", "2", "--queries", "1", "--reduced"]
-    try:
-        serve_mod.main()
-    finally:
-        sys.argv = argv
+    args = SERVE_MODES[mode]
+    monkeypatch.setattr("sys.argv", ["serve", "--reduced", *args])
+    serve_mod.main()
+    out = capsys.readouterr().out
+    n = int(args[args.index("--queries") + 1])
+    done = [ln for ln in out.splitlines() if ln.startswith("query ")]
+    assert len(done) == n, out
+    assert f"over {n} queries" in out
+    if "--prefix-cache" in args:
+        hits = int(out.split("prefix cache: ")[1].split()[0])
+        assert hits > 0, out
