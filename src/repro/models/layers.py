@@ -97,20 +97,26 @@ def init_attention(key, d: int, n_heads: int, n_kv: int, head_dim: int,
     return p
 
 
-def _gqa_scores(q: jax.Array, k: jax.Array) -> jax.Array:
-    """q: (b, sq, h, e), k: (b, sk, n, e) -> scores (b, n, g, sq, sk)."""
+def _gqa_scores(q: jax.Array, k: jax.Array,
+                heads_major: bool = False) -> jax.Array:
+    """q: (b, sq, h, e), k: (b, sk, n, e), or (b, n, sk, e) when
+    ``heads_major`` -> scores (b, n, g, sq, sk)."""
     b, sq, h, e = q.shape
-    n = k.shape[2]
+    n = k.shape[1 if heads_major else 2]
     g = h // n
     q = q.reshape(b, sq, n, g, e)
-    return jnp.einsum("bqnge,bkne->bngqk", q, k,
+    kv = "bnke" if heads_major else "bkne"
+    return jnp.einsum(f"bqnge,{kv}->bngqk", q, k,
                       preferred_element_type=jnp.float32)
 
 
-def _gqa_out(probs: jax.Array, v: jax.Array) -> jax.Array:
-    """probs: (b, n, g, sq, sk), v: (b, sk, n, e) -> (b, sq, h, e)."""
+def _gqa_out(probs: jax.Array, v: jax.Array,
+             heads_major: bool = False) -> jax.Array:
+    """probs: (b, n, g, sq, sk), v: (b, sk, n, e), or (b, n, sk, e) when
+    ``heads_major`` -> (b, sq, h, e)."""
     b, n, g, sq, sk = probs.shape
-    out = jnp.einsum("bngqk,bkne->bqnge", probs, v)
+    kv = "bnke" if heads_major else "bkne"
+    out = jnp.einsum(f"bngqk,{kv}->bqnge", probs, v)
     return out.reshape(b, sq, n * g, out.shape[-1])
 
 
@@ -119,16 +125,19 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
         kv_positions: Optional[jax.Array] = None,
         kv_valid_len: Optional[jax.Array] = None,
         window: int = 0,
-        bias_extra: Optional[jax.Array] = None) -> jax.Array:
+        bias_extra: Optional[jax.Array] = None,
+        kv_heads_major: bool = False) -> jax.Array:
     """Reference multi-head GQA attention (the jnp oracle path; the Pallas
     flash kernels in repro.kernels implement the same contract).
 
-    q (b,sq,h,e), k/v (b,sk,n,e).  ``kv_valid_len`` masks a KV cache tail.
+    q (b,sq,h,e), k/v (b,sk,n,e), or (b,n,sk,e) when ``kv_heads_major``
+    (the KV cache's order).  ``kv_valid_len`` masks a KV cache tail.
     ``window`` > 0 enables sliding-window attention (sub-quadratic archs).
     """
     b, sq, h, e = q.shape
-    sk = k.shape[1]
-    scores = _gqa_scores(q, k) / jnp.sqrt(e).astype(jnp.float32)
+    sk = k.shape[2 if kv_heads_major else 1]
+    scores = _gqa_scores(q, k, kv_heads_major) / jnp.sqrt(e).astype(
+        jnp.float32)
     if bias_extra is not None:
         scores = scores + bias_extra
     mask = None
@@ -154,7 +163,7 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
     probs = jax.nn.softmax(scores, axis=-1)
     # rows that are fully masked produce NaN; zero them out
     probs = jnp.where(jnp.isnan(probs), 0.0, probs).astype(v.dtype)
-    return _gqa_out(probs, v)
+    return _gqa_out(probs, v, kv_heads_major)
 
 
 def mha_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -219,7 +228,9 @@ def attention(p: Params, x: jax.Array, *, positions: jax.Array,
               ) -> Tuple[jax.Array, Optional[Params]]:
     """Full attention block: qkv projection + rope + mha + output proj.
 
-    cache: {"k": (b, S, n, e), "v": ...} updated at ``cache_idx``.
+    cache: {"k": (b, n, S, e), "v": ...} updated at ``cache_idx``.  The
+    cache is heads-major, the order the attention einsums read, so a
+    step never relays it.
     kv_override: precomputed (k, v) for cross-attention (no rope on kv).
     """
     dtype = x.dtype
@@ -244,29 +255,25 @@ def attention(p: Params, x: jax.Array, *, positions: jax.Array,
         if cache is not None:
             # decode / chunked prefill: write new kv at cache_idx, attend to
             # the whole (valid prefix of the) cache
-            S = cache["k"].shape[1]
+            S = cache["k"].shape[2]
             sq = q.shape[1]
             k_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), cache_idx, axis=1)
+                cache["k"], k.transpose(0, 2, 1, 3).astype(cache["k"].dtype),
+                cache_idx, axis=2)
             v_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), cache_idx, axis=1)
+                cache["v"], v.transpose(0, 2, 1, 3).astype(cache["v"].dtype),
+                cache_idx, axis=2)
             cache = {"k": k_cache, "v": v_cache}
             valid = (cache_idx + sq) * jnp.ones((x.shape[0],), jnp.int32)
             out = mha(q, k_cache, v_cache, causal=True,
                       q_positions=positions,
                       kv_positions=jnp.arange(S), kv_valid_len=valid,
-                      window=window)
+                      window=window, kv_heads_major=True)
         else:
             out = mha(q, k, v, causal=causal, q_positions=positions,
                       kv_positions=positions, window=window)
     y = jnp.einsum("bshe,hed->bsd", out.astype(dtype), p["wo"])
     return y, cache
-
-
-def init_cache_attention(batch: int, max_len: int, n_kv: int, head_dim: int,
-                         dtype) -> Params:
-    return {"k": jnp.zeros((batch, max_len, n_kv, head_dim), dtype),
-            "v": jnp.zeros((batch, max_len, n_kv, head_dim), dtype)}
 
 
 # ---------------------------------------------------------------------------

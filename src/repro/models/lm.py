@@ -94,7 +94,7 @@ def _attend(p, cfg: ModelConfig, x, *, positions, causal, cache, cache_idx,
     """Dense attention with optional ring (windowed) cache."""
     if cache is not None and "pos" in cache:
         # ring buffer: write at idx % W
-        W = cache["k"].shape[1]
+        W = cache["k"].shape[2]
         s = x.shape[1]
         slots = (cache_idx + jnp.arange(s)) % W
         q = jnp.einsum("bsd,dhe->bshe", x, p["wq"])
@@ -106,11 +106,13 @@ def _attend(p, cfg: ModelConfig, x, *, positions, causal, cache, cache_idx,
             k, v = k + p["bk"], v + p["bv"]
         q = L.apply_rope(q, positions, cfg.rope_theta)
         k = L.apply_rope(k, positions, cfg.rope_theta)
-        kc = cache["k"].at[:, slots].set(k.astype(cache["k"].dtype))
-        vc = cache["v"].at[:, slots].set(v.astype(cache["v"].dtype))
+        kc = cache["k"].at[:, :, slots].set(
+            k.transpose(0, 2, 1, 3).astype(cache["k"].dtype))
+        vc = cache["v"].at[:, :, slots].set(
+            v.transpose(0, 2, 1, 3).astype(cache["v"].dtype))
         pc = cache["pos"].at[slots].set(positions.astype(jnp.int32))
         out = L.mha(q, kc, vc, causal=True, q_positions=positions,
-                    kv_positions=pc, window=window)
+                    kv_positions=pc, window=window, kv_heads_major=True)
         y = jnp.einsum("bshe,hed->bsd", out.astype(x.dtype), p["wo"])
         return y, {"k": kc, "v": vc, "pos": pc}
     return L.attention(p, x, positions=positions, theta=cfg.rope_theta,
@@ -245,9 +247,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
     cache: Params = {"idx": jnp.zeros((), jnp.int32)}
     fam = cfg.family
 
+    # keys and values heads-major, (layer, b, n, S, e): the order the
+    # attention einsums read, so no step relays a layer's slice
     def attn_cache(n_layers, length, ring=False):
-        c = {"k": jnp.zeros((n_layers, batch, length, nkv, hd), dtype),
-             "v": jnp.zeros((n_layers, batch, length, nkv, hd), dtype)}
+        c = {"k": jnp.zeros((n_layers, batch, nkv, length, hd), dtype),
+             "v": jnp.zeros((n_layers, batch, nkv, length, hd), dtype)}
         if ring:
             c["pos"] = jnp.full((n_layers, length), NEG_POS, jnp.int32)
         return c
@@ -488,7 +492,7 @@ def _run_hybrid(params, cfg, x, positions, cache, cache_idx, new_cache):
     every = cfg.ssm.attn_every
     n_attn = cfg.num_layers // every
     # ring caches are allocated at exactly the window size
-    W = cache["attn"]["k"].shape[2] if (
+    W = cache["attn"]["k"].shape[3] if (
         cache is not None and "pos" in cache["attn"]) else 0
 
     mc = None if cache is None else cache["mamba"]

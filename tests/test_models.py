@@ -85,4 +85,5 @@ def test_zamba2_windowed_long_context_cache():
     # force the long-context window path
     cache = lm.init_cache(cfg, 1, 40000)
     assert "pos" in cache["attn"]
-    assert cache["attn"]["k"].shape[2] == 4096   # window, not 40000
+    # heads-major (layers, b, n_kv, W, e): the window is the position axis
+    assert cache["attn"]["k"].shape[3] == 4096   # window, not 40000

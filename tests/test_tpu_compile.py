@@ -10,6 +10,7 @@ module is imported: only one process at a time may load the TPU library,
 and under pytest-xdist every worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -118,6 +119,45 @@ def test_lm_programs_copy_no_weight_stack_for_v5e(one_chip, role, max_len):
             (agent._decode, (_sds((CALL_WIDTH,), "int32", one_chip), cache))):
         memory = program.lower(params, *args).compile().memory_analysis()
         assert memory.temp_size_in_bytes < wk.size * wk.dtype.itemsize
+
+
+_COPY = re.compile(r"= \w+\[([\d,]*)\]\S* copy\(")
+
+
+@pytest.mark.parametrize("program", ["_prefill", "_decode"])
+@pytest.mark.parametrize("role,max_len", [("chat", 512), ("search", 256)])
+def test_lm_programs_copy_no_kv_slice_for_v5e(one_chip, role, max_len,
+                                              program):
+    """An agent's prefill or decode program at published widths copies no
+    layer's KV slice: the cache is heads-major, the order the attention
+    einsums read, so no step relays one.  (With the cache in (b, S, n, e)
+    order each program held four such copies a layer, K and V into the
+    einsums' order and back, 2.42 GB of traffic a 4B decode step.)  A
+    copy is a KV slice by its dims, not its element count alone: the
+    1.7B's q/k/v weight relayout copies a (2048, 8, 128) slice, as many
+    elements as the search agent's (8, 256, 8, 128) cache slice."""
+    from repro.rag.agents import LMAgent
+    from repro.rag.embedder import CALL_WIDTH
+
+    cfg = get_family("qwen3")[role]
+    agent = LMAgent(cfg, None, max_len=max_len)
+    params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                          jax.eval_shape(agent.model.init,
+                                         jax.random.PRNGKey(0)))
+    if program == "_prefill":
+        args = (_sds((CALL_WIDTH, 16), "int32", one_chip),)
+    else:
+        cache = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                             jax.eval_shape(lambda: agent.model.init_cache(
+                                 CALL_WIDTH, max_len)))
+        args = (_sds((CALL_WIDTH,), "int32", one_chip), cache)
+    text = getattr(agent, program).lower(params, *args).compile().as_text()
+    kv_slice = sorted((CALL_WIDTH, max_len, cfg.num_kv_heads,
+                       cfg.resolved_head_dim))
+    copies = [dims for dims in _COPY.findall(text)
+              if sorted(int(d) for d in dims.split(",") if d not in ("", "1"))
+              == kv_slice]
+    assert not copies
 
 
 def test_moe_share_programs_compile_for_v5e(one_chip, monkeypatch):
